@@ -1,5 +1,8 @@
 """Paper Fig 6/7 analog: distributed (MPI-backend analog) per-epoch time.
 
+A CPU-only harness: its epoch times are XLA-CPU times on 8 virtual host
+devices, not device measurements (the on-chip benchmark replaces it).
+
 Two sweeps, both in a subprocess with 8 host devices so the parent keeps 1:
 
 1. Arch x regime epoch times under the plan-driven distributed trainer
@@ -160,6 +163,7 @@ _CODE = textwrap.dedent("""
 def run() -> list[str]:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     res = subprocess.run([sys.executable, "-c", _CODE], env=env,
                          capture_output=True, text=True, timeout=3600)

@@ -1,8 +1,10 @@
 """Distributed GNN training — the paper's MPI backend, end to end.
 
-Re-executes itself with 8 host devices, partitions a synthetic graph with
-the hierarchical partitioner (Alg 4), builds per-rank local|ghost views,
-and trains with halo exchange + pipelined per-layer gradient psum.
+A CPU-only harness: it re-executes itself with 8 virtual host devices
+(``--xla_force_host_platform_device_count``), partitions a synthetic graph
+with the hierarchical partitioner (Alg 4), builds per-rank local|ghost
+views, and trains with halo exchange + pipelined per-layer gradient psum.
+The four-chip run of the same path is ``python chip_smoke.py --chips 4``.
 
 Run:  PYTHONPATH=src python examples/distributed_gnn.py
 """
@@ -16,11 +18,13 @@ def main():
         env = dict(os.environ)
         env["_DIST_CHILD"] = "1"
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        env["JAX_PLATFORMS"] = "cpu"
         raise SystemExit(subprocess.run([sys.executable, __file__],
                                         env=env).returncode)
 
     import jax
 
+    from repro.common.jit import configure_compile_cache
     from repro.core.halo import build_distributed_graph
     from repro.core.lowering import lower_distributed
     from repro.core.partitioner import hierarchical_partition
@@ -29,6 +33,7 @@ def main():
     from repro.training.optimizer import adam
     from repro.training.trainer import DistributedGNNTrainer
 
+    configure_compile_cache()
     print(f"devices: {len(jax.devices())}")
     # corafull analog: 95%-sparse bag-of-words features, so the per-rank
     # Alg-1 decision binds the distributed sparse input path
@@ -50,8 +55,7 @@ def main():
     plan = lower_distributed(config, dist)
     print(plan.describe())
 
-    trainer = DistributedGNNTrainer(dist, config, adam(0.01), plan=plan,
-                                    interpret=True)
+    trainer = DistributedGNNTrainer(dist, config, adam(0.01), plan=plan)
     for epoch in range(5):
         loss = trainer.train_epoch()
         print(f"epoch {epoch + 1}  global loss {loss:.4f}")

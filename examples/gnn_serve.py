@@ -12,6 +12,7 @@ Run:  PYTHONPATH=src python examples/gnn_serve.py
 """
 import numpy as np
 
+from repro.common.jit import configure_compile_cache
 from repro.graph.datasets import generate_dataset
 from repro.models.gnn import GNNConfig
 from repro.serving.gnn_engine import GNNRequest, GNNServingEngine
@@ -20,6 +21,7 @@ from repro.training.trainer import MiniBatchTrainer
 
 
 def main():
+    configure_compile_cache()
     ds = generate_dataset("flickr", scale=0.01, seed=0)
     config = GNNConfig(kind="SAGE",
                        layer_dims=[ds.features.shape[1], 32, ds.n_classes],

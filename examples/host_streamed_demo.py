@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.jit import configure_compile_cache
 from repro.core.pipeline import arch_layer_fns, pipelined_value_and_grad
 from repro.graph.datasets import generate_dataset
 from repro.models.gnn import GNNConfig, LayerOps, init_params
@@ -23,6 +24,7 @@ DEVICE_BUDGET_BYTES = 96 * 1024
 
 
 def main():
+    configure_compile_cache()
     ds = generate_dataset("corafull", scale=0.02, seed=0)
     config = GNNConfig(kind="GCN",
                        layer_dims=[ds.features.shape[1], 32, ds.n_classes],

@@ -10,6 +10,7 @@ accuracy comes from the dataset's val/test splits.
 
 Run:  PYTHONPATH=src python examples/minibatch_sage.py
 """
+from repro.common.jit import configure_compile_cache
 from repro.graph.datasets import generate_dataset
 from repro.models.gnn import GNNConfig
 from repro.training.optimizer import adam
@@ -17,6 +18,7 @@ from repro.training.trainer import MiniBatchTrainer
 
 
 def main():
+    configure_compile_cache()
     ds = generate_dataset("flickr", scale=0.02, seed=0)
     print(f"graph: {ds.graph.n_rows} nodes, {ds.graph.nnz} edges, "
           f"feature sparsity {ds.feature_sparsity:.2%}, "

@@ -16,10 +16,12 @@ serving, the resilient runtime (DESIGN.md §13) wraps every trainer in
 guarded steps with skip → LR-backoff → rollback, deterministic fault
 injection, and elastic recovery — see runtime/resilience.py.
 """
+from repro.common.jit import configure_compile_cache
 from repro.core.dsl import GNNProgram
 from repro.graph.datasets import generate_dataset
 
 def main():
+    configure_compile_cache()
     dataset = generate_dataset("corafull", scale=0.02, seed=0)
     print(f"graph: {dataset.graph.n_rows} nodes, {dataset.graph.nnz} edges, "
           f"feature sparsity {dataset.feature_sparsity:.2%}")

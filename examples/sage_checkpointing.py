@@ -10,6 +10,7 @@ import tempfile
 
 import jax
 
+from repro.common.jit import configure_compile_cache
 from repro.graph.datasets import generate_dataset
 from repro.models.gnn import GNNConfig, GNNModel
 from repro.runtime.checkpoint import latest_step
@@ -18,6 +19,7 @@ from repro.training.trainer import FullBatchTrainer
 
 
 def main():
+    configure_compile_cache()
     ds = generate_dataset("flickr", scale=0.01, seed=0)
     cfg = GNNConfig(kind="SAGE", aggregation="max",
                     layer_dims=[ds.features.shape[1], 32, ds.n_classes])

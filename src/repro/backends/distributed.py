@@ -311,7 +311,7 @@ def debug_halo_check(dist, features=None, mesh=None) -> None:
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from repro.common.compat import shard_map
+    from jax import shard_map
     from repro.core.halo import halo_exchange_debug
 
     P_ranks = dist.n_ranks

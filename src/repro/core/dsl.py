@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.jit import jit_hoisted
 from repro.core.lowering import ModelPlan, lower
 from repro.core.sparsity import PAPER_GAMMA_DEFAULT, SparsityDecision
 from repro.graph.csr import CSRGraph
@@ -67,18 +68,26 @@ class CompiledProgram:
     def describe_plan(self) -> str:
         return self.plan.describe()
 
-    def train_epoch(self) -> dict:
+    def _step(self):
         if self._train_step is None:
             model, opt = self.model, self.opt
 
-            @jax.jit
+            @jit_hoisted  # the plan's operands ride as arguments
             def step(params, opt_state, x, labels, mask):
                 loss, grads = jax.value_and_grad(model.loss_fn)(params, x, labels, mask)
                 new_params, new_opt_state = opt.update(grads, opt_state, params)
                 return new_params, new_opt_state, loss
 
             self._train_step = step
-        self.params, self.opt_state, loss = self._train_step(
+        return self._train_step
+
+    def compile_step(self) -> None:
+        """Compile the epoch step ahead of the first ``train_epoch``."""
+        self._step().compile(self.params, self.opt_state, self.x,
+                             self.labels, self.train_mask)
+
+    def train_epoch(self) -> dict:
+        self.params, self.opt_state, loss = self._step()(
             self.params, self.opt_state, self.x, self.labels, self.train_mask
         )
         self._epoch += 1

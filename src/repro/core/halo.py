@@ -29,8 +29,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.common.compat import axis_size as compat_axis_size
-
 from repro.core.partitioner import PartitionResult, build_local_views
 from repro.graph.csr import CSRGraph, csr_from_edges, csr_to_bsr
 
@@ -346,7 +344,7 @@ def _halo_exchange_impl(
     (host-computed in ``build_distributed_graph``); a shift whose
     ``send_idx`` row is all -1 on *every* rank exchanges nothing, so
     skipping it is exact. ``None`` issues all P-1 shifts."""
-    P = compat_axis_size(axis_name)
+    P = jax.lax.axis_size(axis_name)
     f = x_local.shape[-1]
     ghost = jnp.zeros((n_ghost, f), dtype=x_local.dtype)
     for s in (range(1, P) if shifts is None else shifts):
@@ -386,7 +384,7 @@ def halo_exchange_debug(
     ``halo.schedule_paired`` checks in ``core/verify.py``. The host-side
     ``debug_halo_check`` turns a nonzero difference into an error.
     """
-    P = compat_axis_size(axis_name)
+    P = jax.lax.axis_size(axis_name)
     f = x_local.shape[-1]
     ghost = jnp.zeros((n_ghost, f), dtype=x_local.dtype)
     shipped = jnp.zeros((), jnp.float32)
@@ -424,7 +422,7 @@ def halo_exchange_transpose(
     scatter into scatter/reverse-ppermute/gather — the reverse exchange the
     backward pass issues for ghost gradients. ``shifts`` mirrors the
     forward's live-shift set (a dead forward shift is dead in reverse)."""
-    P = compat_axis_size(axis_name)
+    P = jax.lax.axis_size(axis_name)
     out = jnp.zeros((n_local, ghost.shape[-1]), dtype=ghost.dtype)
     for s in (range(1, P) if shifts is None else shifts):
         slot = recv_slot[s - 1]

@@ -495,9 +495,12 @@ def csr_to_bsr(csr: CSRGraph, br: int = 8, bc: Optional[int] = None) -> BSRMatri
     order = np.lexsort((all_cols, all_rows))  # (row, col) sorted
 
     n_blocks = all_rows.shape[0]
+    # scatter straight into sorted position: one [n_blocks, br, bc] buffer
+    # (gigabytes at published graph sizes), never a permuted copy of it
+    sorted_pos = np.empty(n_blocks, np.int64)
+    sorted_pos[order] = np.arange(n_blocks)
     blocks = np.zeros((n_blocks, br, bc), dtype=np.float32)
-    np.add.at(blocks, (inv, rows % br, cols % bc), csr.data)
-    blocks = blocks[order]
+    np.add.at(blocks, (sorted_pos[inv], rows % br, cols % bc), csr.data)
     block_rows = all_rows[order]
     first_flags = np.ones(n_blocks, dtype=np.int32)
     first_flags[1:] = (block_rows[1:] != block_rows[:-1]).astype(np.int32)

@@ -28,11 +28,12 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.backends import DistributedBackend, compose_epilogue, get_backend
 from repro.backends.gather import EdgeListOperand
-from repro.common.compat import shard_map
+from repro.common.jit import jit_hoisted
 from repro.core.aggregate import gather_scatter_aggregate
 from repro.core.halo import DistributedGraph, GhostBufferRing, halo_exchange
 from repro.core.lowering import (
@@ -92,13 +93,14 @@ class FullBatchTrainer:
         self.injector = injector
         self.guard = GuardRunner(guard) if guard is not None else None
 
-        @jax.jit
+        # the model's plan operands ride as arguments, not program constants
+        @jit_hoisted
         def step(params, opt_state, x, labels, mask):
             loss, grads = jax.value_and_grad(model.loss_fn)(params, x, labels, mask)
             params, opt_state = opt.update(grads, opt_state, params)
             return params, opt_state, loss
 
-        @jax.jit
+        @jit_hoisted
         def step_guarded(params, opt_state, x, labels, mask, scale, poison):
             loss, grads = jax.value_and_grad(model.loss_fn)(params, x, labels, mask)
             grads = jax.tree_util.tree_map(

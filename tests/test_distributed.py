@@ -164,7 +164,7 @@ def test_reverse_halo_is_linear_transpose():
         import json
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from repro.common.compat import shard_map
+        from jax import shard_map
         from repro.core.halo import (_halo_exchange_impl, build_distributed_graph,
                                      halo_exchange, halo_exchange_transpose)
         from repro.core.partitioner import hierarchical_partition
@@ -220,7 +220,7 @@ def test_distributed_loss_decreases_and_compression():
     code = textwrap.dedent("""
         import json
         import jax, jax.numpy as jnp, numpy as np
-        from repro.common.compat import shard_map
+        from jax import shard_map
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.training.grad import compressed_psum, quantize_int8, dequantize_int8
 

@@ -385,3 +385,43 @@ def test_gt_layer_residual_and_training_step(rng):
     stepped = jax.tree_util.tree_map(lambda p, g_: p - 0.1 * g_, params, grads)
     loss1 = model.loss_fn(stepped, xj, labels, mask)
     assert float(loss1) < float(loss0)
+
+
+@pytest.mark.parametrize("window", [1, 4])
+def test_windowed_attention_matches_whole_stream(rng, window):
+    """All three attention kernels over windows smaller than a hub row
+    (block-row 0 touches every block-column) equal the one-window run: the
+    (out, m, l) / dc / (dzv, dd) tiles resume across window boundaries and
+    the forward finalises once, at the true last_in_row."""
+    from repro.graph.csr import csr_to_bsr
+    from repro.kernels.bsr_attention import (bsr_attention_bwd_col,
+                                             bsr_attention_bwd_row)
+
+    n, heads, dh = 40, 2, 8
+    g = csr_from_edges(
+        np.concatenate([rng.integers(0, n, 150), np.arange(n)]),
+        np.concatenate([rng.integers(0, n, 150), np.zeros(n, np.int64)]), n)
+    a, at = csr_to_bsr(g, br=8, bc=8), csr_to_bsr(g.transpose(), br=8, bc=8)
+    assert np.bincount(a.block_rows)[0] > window
+    arr = lambda v: jnp.asarray(rng.standard_normal(v).astype(np.float32))
+    stat, feat = (n, heads), (n, heads * dh)
+    adst, asrc, z, dy, r = arr(stat), arr(stat), arr(feat), arr(feat), arr(stat)
+    fwd_idx = [jnp.asarray(v) for v in (a.block_rows, a.block_cols,
+                                        a.first_in_row)]
+    bwd_idx = [jnp.asarray(v) for v in (at.block_rows, at.block_cols,
+                                        at.first_in_row)]
+    kw = dict(n_rows_padded=n, heads=heads, dh=dh, interpret=True)
+    for w in (None, window):
+        out, m, l = bsr_attention_fwd(
+            *fwd_idx, jnp.asarray(a.last_in_row), jnp.asarray(a.blocks),
+            adst, asrc, z, window=w, **kw)
+        dc = bsr_attention_bwd_row(*fwd_idx, jnp.asarray(a.blocks), adst,
+                                   asrc, z, dy, r, m, l, window=w, **kw)
+        dzv, dd = bsr_attention_bwd_col(*bwd_idx, jnp.asarray(at.blocks),
+                                        asrc, adst, z, dy, r, m, l,
+                                        window=w, **kw)
+        got = [np.asarray(v) for v in (out, m, l, dc, dzv, dd)]
+        if w is None:
+            whole = got
+    for g_, w_ in zip(got, whole):
+        np.testing.assert_allclose(g_, w_, atol=1e-5, rtol=1e-5)

@@ -229,3 +229,57 @@ def test_transpose_pair_is_adjoint(rng):
     np.testing.assert_allclose(
         float(jnp.vdot(ax, y)), float(jnp.vdot(x, aty)), rtol=1e-4
     )
+
+
+def _hub_bsr(rng, n=64, bc=8):
+    """BSR whose block-row 0 is a hub: it touches every block-column, so it
+    straddles several small windows of the block stream."""
+    src = np.concatenate([rng.integers(0, n, 200), np.arange(n)])
+    dst = np.concatenate([rng.integers(0, n, 200), np.zeros(n, np.int64)])
+    return csr_to_bsr(csr_from_edges(src, dst, n), br=8, bc=bc)
+
+
+@pytest.mark.parametrize("window", [1, 3, 5])
+def test_windowed_stream_matches_whole(rng, window):
+    """Windows smaller than the hub row: every block-row that straddles a
+    window boundary resumes from the carry, the fused epilogue fires once
+    at the true last_in_row, and all three kernels match the dense oracle."""
+    bsr = _hub_bsr(rng)
+    assert np.bincount(bsr.block_rows)[0] > window  # hub spans windows
+    dense = np.zeros((bsr.padded_rows, bsr.padded_cols), np.float32)
+    d = bsr.to_dense()
+    dense[: d.shape[0], : d.shape[1]] = d
+    f = 16
+    x = rng.standard_normal((bsr.padded_cols, f)).astype(np.float32)
+    s = rng.standard_normal((bsr.padded_rows, f)).astype(np.float32)
+    b = rng.standard_normal((1, f)).astype(np.float32)
+    m = (rng.random((bsr.padded_cols, f)) < 0.5).astype(np.float32)
+    idx = (jnp.asarray(bsr.block_rows), jnp.asarray(bsr.block_cols),
+           jnp.asarray(bsr.first_in_row))
+    kw = dict(n_rows_padded=bsr.padded_rows, bf=f, interpret=True,
+              window=window)
+    y = kops.bsr_spmm(*idx, jnp.asarray(bsr.blocks), jnp.asarray(x), **kw)
+    np.testing.assert_allclose(np.asarray(y), dense @ x, atol=1e-4, rtol=1e-4)
+    y, mask = bsr_spmm_fused_epilogue(
+        *idx, jnp.asarray(bsr.last_in_row), jnp.asarray(bsr.blocks),
+        jnp.asarray(x), jnp.asarray(s), jnp.asarray(b), jnp.float32(0.7),
+        activation="relu", **kw)
+    z = dense @ x + 0.7 * s + b
+    np.testing.assert_allclose(np.asarray(y), np.maximum(z, 0.0),
+                               atol=1e-4, rtol=1e-4)
+    np.testing.assert_array_equal(np.asarray(mask), (z > 0).astype(np.float32))
+    y = bsr_spmm_masked(*idx, jnp.asarray(bsr.blocks), jnp.asarray(x),
+                        jnp.asarray(m), **kw)
+    np.testing.assert_allclose(np.asarray(y), dense @ (m * x),
+                               atol=1e-4, rtol=1e-4)
+
+
+def test_block_windows_fit_smem_budget():
+    """Windows tile the stream exactly, in order, each within the budget."""
+    from repro.kernels.bsr_spmm import SMEM_INDEX_WORDS, block_windows
+
+    for n_blocks, streams in ((1_081_262, 3), (917_090, 4), (5, 3)):
+        wins = block_windows(n_blocks, streams)
+        assert wins[0][0] == 0 and wins[-1][1] == n_blocks
+        assert all(a[1] == b[0] for a, b in zip(wins, wins[1:]))
+        assert all((e - s) * streams <= SMEM_INDEX_WORDS for s, e in wins)

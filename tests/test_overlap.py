@@ -365,7 +365,7 @@ def test_live_shift_exchange_matches_full_ring():
         import json
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from repro.common.compat import shard_map
+        from jax import shard_map
         from repro.core.halo import build_distributed_graph, halo_exchange
         from repro.core.partitioner import hierarchical_partition
         from repro.graph.datasets import generate_dataset

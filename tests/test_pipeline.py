@@ -109,7 +109,7 @@ def test_pipelined_psum_ordering_in_jaxpr(rng, kind, agg):
         return pipelined_value_and_grad(layer_fns, p, x, labels, mask,
                                         axis_name="data")[0]
 
-    from repro.common.compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.asarray(jax.devices()[:1]), ("data",))
