@@ -18,8 +18,10 @@ epoch-1 global loss must agree within 1e-3 (relative) with the
 single-device full-batch loss (``gather`` backend, highest precision) from
 the same params.
 
-Times printed here are smoke readings, not benchmark numbers. The script
-exits non-zero when no TPU is present; the last line of a passing run is
+Times printed here are smoke readings, not benchmark numbers. Each phase
+also prints the program's own spans and counters over its set-up and
+epochs (``repro.common.spans``; README "Tracing"). The script exits
+non-zero when no TPU is present; the last line of a passing run is
 ``{"ok": true, "device": {...}}``.
 """
 from __future__ import annotations
@@ -55,6 +57,24 @@ def rel_err(got, want) -> float:
     return float(np.abs(got - want).max() / np.abs(want).max())
 
 
+def span_table() -> str:
+    """The program's spans and counters since the last ``reset()``
+    (``repro.common.spans``), one row per path: count, and total, self
+    and longest seconds."""
+    from repro.common.spans import snapshot
+
+    snap = snapshot()
+    rows = [f"{'span':<44} {'count':>6} {'total_s':>10} {'self_s':>10} "
+            f"{'max_s':>10}"]
+    rows += [f"{path:<44} {s['count']:>6} {s['total_s']:>10.4f} "
+             f"{s['self_s']:>10.4f} {s['max_s']:>10.4f}"
+             for path, s in sorted(snap["spans"].items())]
+    rows += [f"{'counter':<44} {'n':>6}"]
+    rows += [f"{path:<44} {n:>6g}"
+             for path, n in sorted(snap["counters"].items())]
+    return "\n".join(rows)
+
+
 def program(ds, arch):
     from repro.core.dsl import GNNProgram
 
@@ -72,10 +92,12 @@ def logits(compiled, params):
 
 
 def single_chip_phase(ds, arch: str, agg_primitive: str) -> None:
+    from repro.common import spans
     from repro.core.verify import check_plan
     from repro.kernels.ops import default_interpret
 
     log(f"== phase {arch} ==")
+    spans.reset()
     t = time.perf_counter()
     compiled = program(ds, arch).compile(validate="off")
     t_lower = time.perf_counter() - t
@@ -113,6 +135,7 @@ def single_chip_phase(ds, arch: str, agg_primitive: str) -> None:
         raise SystemExit(f"{arch}: loss not finite and falling: {losses}")
     dev = jax.devices()[0]
     log(f"{arch}: peak_bytes_in_use={peak_bytes(dev)}")
+    log(f"{arch}: program spans and counters\n{span_table()}")
 
     got = logits(compiled, params0)
     del compiled
@@ -127,6 +150,7 @@ def single_chip_phase(ds, arch: str, agg_primitive: str) -> None:
 
 
 def distributed_phase(ds, n_chips: int) -> None:
+    from repro.common import spans
     from repro.core.halo import build_distributed_graph
     from repro.core.lowering import lower_distributed
     from repro.core.partitioner import hierarchical_partition
@@ -135,6 +159,7 @@ def distributed_phase(ds, n_chips: int) -> None:
     from repro.training.trainer import DistributedGNNTrainer
 
     log(f"== phase distributed GCN on {n_chips} chips ==")
+    spans.reset()
     dims = [ds.features.shape[1], *HIDDEN, ds.n_classes]
     config = GNNConfig(kind="GCN", layer_dims=dims, aggregation="gcn")
     t = time.perf_counter()
@@ -176,6 +201,7 @@ def distributed_phase(ds, n_chips: int) -> None:
         raise SystemExit(f"distributed loss not finite and falling: {losses}")
     for d in jax.devices()[:n_chips]:
         log(f"device {d.id}: peak_bytes_in_use={peak_bytes(d)}")
+    log(f"distributed: program spans and counters\n{span_table()}")
 
     del trainer
     gc.collect()

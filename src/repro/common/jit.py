@@ -6,6 +6,8 @@ from pathlib import Path
 
 import jax
 
+from repro.common.spans import count, span
+
 # the checkout root (src/repro/common/jit.py -> three levels up)
 _CHECKOUT = Path(__file__).resolve().parents[3]
 
@@ -38,6 +40,11 @@ class jit_hoisted:
     minutes to compile. Here ``fn`` is traced once per argument signature
     and compiled ahead of time, with the jaxpr's constants passed as
     ordinary device-resident arguments.
+
+    Spans: ``compile_step`` around ``compile``, ``dispatch`` around a call;
+    building an entry counts ``compiles`` and nests ``trace`` (the jaxpr),
+    ``consts`` (their upload), ``lower`` (StableHLO, Mosaic included) and
+    ``compile`` (XLA, or a load from the persistent cache).
     """
 
     def __init__(self, fn):
@@ -49,19 +56,28 @@ class jit_hoisted:
         key = (tree, tuple(jax.typeof(leaf) for leaf in leaves),
                jax.config.jax_default_matmul_precision)
         if key not in self._cache:
-            closed, out_shape = jax.make_jaxpr(
-                self._fn, return_shape=True)(*args)
+            count("compiles")
+            with span("trace"):
+                closed, out_shape = jax.make_jaxpr(
+                    self._fn, return_shape=True)(*args)
             jaxpr = closed.jaxpr
-            consts = jax.device_put(closed.consts)
+            with span("consts"):
+                consts = jax.device_put(closed.consts)
             run = jax.jit(lambda c, flat: jax.core.eval_jaxpr(jaxpr, c, *flat))
-            self._cache[key] = (run.lower(consts, leaves).compile(), consts,
+            with span("lower"):
+                lowered = run.lower(consts, leaves)
+            with span("compile"):
+                compiled = lowered.compile()
+            self._cache[key] = (compiled, consts,
                                 jax.tree_util.tree_structure(out_shape))
         return self._cache[key], leaves
 
+    @span("compile_step")
     def compile(self, *args) -> None:
         """Trace and compile for these arguments without running."""
         self._entry(args)
 
+    @span("dispatch")
     def __call__(self, *args):
         (run, consts, out_tree), leaves = self._entry(args)
         return jax.tree_util.tree_unflatten(out_tree, run(consts, leaves))

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.backends import Backend, select_backend
+from repro.common.spans import span
 from repro.graph.csr import CSRGraph
 
 Aggregation = Literal["sum", "mean", "gcn", "max"]
@@ -103,6 +104,7 @@ class FusedGraphOp:
         )
 
 
+@span("graph_op")
 def make_fused_aggregate(
     graph: CSRGraph,
     aggregation: Aggregation = "gcn",

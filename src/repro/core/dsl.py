@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.common.jit import jit_hoisted
+from repro.common.spans import span
 from repro.core.lowering import ModelPlan, lower
 from repro.core.sparsity import PAPER_GAMMA_DEFAULT, SparsityDecision
 from repro.graph.csr import CSRGraph
@@ -74,8 +75,9 @@ class CompiledProgram:
 
             @jit_hoisted  # the plan's operands ride as arguments
             def step(params, opt_state, x, labels, mask):
-                loss, grads = jax.value_and_grad(model.loss_fn)(params, x, labels, mask)
-                new_params, new_opt_state = opt.update(grads, opt_state, params)
+                loss, grads = model.loss_and_grads(params, x, labels, mask)
+                with jax.named_scope("optimizer"):
+                    new_params, new_opt_state = opt.update(grads, opt_state, params)
                 return new_params, new_opt_state, loss
 
             self._train_step = step
@@ -87,11 +89,14 @@ class CompiledProgram:
                              self.labels, self.train_mask)
 
     def train_epoch(self) -> dict:
-        self.params, self.opt_state, loss = self._step()(
-            self.params, self.opt_state, self.x, self.labels, self.train_mask
-        )
+        with span("epoch", step=self._epoch):
+            self.params, self.opt_state, loss = self._step()(
+                self.params, self.opt_state, self.x, self.labels, self.train_mask
+            )
+            with span("loss_read"):
+                loss = float(loss)
         self._epoch += 1
-        return {"epoch": self._epoch, "loss": float(loss)}
+        return {"epoch": self._epoch, "loss": loss}
 
     def accuracy(self) -> float:
         return float(self.model.accuracy(self.params, self.x, self.labels, self.train_mask))
@@ -181,17 +186,18 @@ class GNNProgram:
             engine=engine, interpret=interpret, use_fused=use_fused,
             layout=layout, fuse_attention=fuse_attention, validate=validate,
         )
-        model = GNNModel(config, self.graph, interpret=interpret,
-                         use_fused=use_fused, plan=plan)
+        with span("init"):
+            model = GNNModel(config, self.graph, interpret=interpret,
+                             use_fused=use_fused, plan=plan)
 
-        params = model.init(jax.random.PRNGKey(self._seed))
-        name, lr, *rest = self._opt_spec
-        opt = get_optimizer(name, lr, *rest, fused=fused_optimizer,
-                            **getattr(self, "_opt_kw", {}))
-        opt_state = opt.init(params)
-        return CompiledProgram(
-            model=model, params=params, opt=opt, opt_state=opt_state,
-            x=jnp.asarray(self.features), labels=jnp.asarray(self.labels),
-            train_mask=jnp.asarray(self.train_mask),
-            plan=plan,
-        )
+            params = model.init(jax.random.PRNGKey(self._seed))
+            name, lr, *rest = self._opt_spec
+            opt = get_optimizer(name, lr, *rest, fused=fused_optimizer,
+                                **getattr(self, "_opt_kw", {}))
+            opt_state = opt.init(params)
+            return CompiledProgram(
+                model=model, params=params, opt=opt, opt_state=opt_state,
+                x=jnp.asarray(self.features), labels=jnp.asarray(self.labels),
+                train_mask=jnp.asarray(self.train_mask),
+                plan=plan,
+            )

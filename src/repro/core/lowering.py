@@ -35,6 +35,7 @@ import jax
 import numpy as np
 
 from repro.backends import Backend, select_backend
+from repro.common.spans import span
 from repro.core.aggregate import FusedGraphOp, _weighted_graph, make_fused_aggregate
 from repro.core.layout import (
     LayoutPlan,
@@ -314,6 +315,7 @@ class SampledModelPlan:
         return "\n".join(lines)
 
 
+@span("lower")
 def lower_sampled(
     config,
     graph: CSRGraph,
@@ -515,6 +517,7 @@ def effective_aggregation(config) -> str:
     return config.aggregation
 
 
+@span("lower")
 def lower_distributed(
     config,
     dist,  # core.halo.DistributedGraph
@@ -772,6 +775,7 @@ def _sparse_expressible(kind: str) -> tuple[bool, str]:
     return False, f"no sparse lowering for {kind}"
 
 
+@span("layout")
 def _resolve_layout(
     graph: CSRGraph,
     f_dim: int,
@@ -823,6 +827,7 @@ def _resolve_layout(
                                source="requested", reordered_graph=g_r)
 
 
+@span("lower")
 def lower(
     config,
     graph: CSRGraph,
@@ -924,67 +929,68 @@ def lower(
         features = np.asarray(features)
 
     layers: list[LayerPlan] = []
-    for i in range(config.n_layers):
-        d_in, d_out = dims[i], dims[i + 1]
-        if i == 0:
-            if features is not None:
-                decision = decide_execution_path(
-                    features, gamma=gamma, n_hidden=d_out)
-                s_input = decision.sparsity
+    with span("decide"):
+        for i in range(config.n_layers):
+            d_in, d_out = dims[i], dims[i + 1]
+            if i == 0:
+                if features is not None:
+                    decision = decide_execution_path(
+                        features, gamma=gamma, n_hidden=d_out)
+                    s_input = decision.sparsity
+                else:
+                    decision = decide_execution_path_from_stats(
+                        0.0, n_nodes, d_in, d_out, gamma=gamma)
             else:
+                s_est = estimate_activation_sparsity(config.activation)
                 decision = decide_execution_path_from_stats(
-                    0.0, n_nodes, d_in, d_out, gamma=gamma)
-        else:
-            s_est = estimate_activation_sparsity(config.activation)
-            decision = decide_execution_path_from_stats(
-                s_est, n_nodes, d_in, d_out, gamma=gamma)
+                    s_est, n_nodes, d_in, d_out, gamma=gamma)
 
-        sparse_xw = None
-        note = ""
-        if decision.mode == "sparse":
-            expressible, expr_note = _sparse_expressible(kind)
-            if i == 0 and features is not None and use_fused and expressible:
-                # operand of the (possibly reordered) feature matrix; bc
-                # adapts to the feature dim — X's columns are features, not
-                # graph nodes, so the adjacency tile does not apply
-                sparse_xw = backend.feature_matmul_sparse(
-                    features_exec, br=lp.br, bc=None, interpret=interpret)
-                path = "sparse"
-                primitive = f"{backend.name}.feature_matmul_sparse"
-                note = expr_note
+            sparse_xw = None
+            note = ""
+            if decision.mode == "sparse":
+                expressible, expr_note = _sparse_expressible(kind)
+                if i == 0 and features is not None and use_fused and expressible:
+                    # operand of the (possibly reordered) feature matrix; bc
+                    # adapts to the feature dim — X's columns are features, not
+                    # graph nodes, so the adjacency tile does not apply
+                    sparse_xw = backend.feature_matmul_sparse(
+                        features_exec, br=lp.br, bc=None, interpret=interpret)
+                    path = "sparse"
+                    primitive = f"{backend.name}.feature_matmul_sparse"
+                    note = expr_note
+                else:
+                    path = "dense"
+                    primitive = f"{backend.name}.feature_matmul_dense"
+                    if not use_fused:
+                        note = "sparse profitable but fusion disabled (use_fused=False)"
+                    elif i > 0:
+                        note = ("sparse profitable but activations are runtime "
+                                "values; no pre-built operand — dense fallback")
+                    elif features is None:
+                        note = "feature matrix unknown at lowering time"
+                    else:
+                        note = expr_note
             else:
                 path = "dense"
                 primitive = f"{backend.name}.feature_matmul_dense"
-                if not use_fused:
-                    note = "sparse profitable but fusion disabled (use_fused=False)"
-                elif i > 0:
-                    note = ("sparse profitable but activations are runtime "
-                            "values; no pre-built operand — dense fallback")
-                elif features is None:
-                    note = "feature matrix unknown at lowering time"
-                else:
-                    note = expr_note
-        else:
-            path = "dense"
-            primitive = f"{backend.name}.feature_matmul_dense"
 
-        epilogue = None
-        if emit_epilogue:
-            epilogue = _epilogue_binding(
-                config, is_last=(i == config.n_layers - 1),
-                sparse_path=sparse_xw is not None)
-        attention = None
-        if is_attn:
-            attention = _attention_binding(config.gat_heads, d_out,
-                                           attn_bound)
+            epilogue = None
+            if emit_epilogue:
+                epilogue = _epilogue_binding(
+                    config, is_last=(i == config.n_layers - 1),
+                    sparse_path=sparse_xw is not None)
+            attention = None
+            if is_attn:
+                attention = _attention_binding(config.gat_heads, d_out,
+                                               attn_bound)
 
-        layers.append(LayerPlan(
-            index=i, op_kind=kind, d_in=d_in, d_out=d_out,
-            feature_path=path, primitive=primitive,
-            agg_primitive=agg_primitive, decision=decision,
-            sparse_xw=sparse_xw, note=note, epilogue=epilogue,
-            attention=attention, layout=lp,
-        ))
+            layers.append(LayerPlan(
+                index=i, op_kind=kind, d_in=d_in, d_out=d_out,
+                feature_path=path, primitive=primitive,
+                agg_primitive=agg_primitive, decision=decision,
+                sparse_xw=sparse_xw, note=note, epilogue=epilogue,
+                attention=attention, layout=lp,
+            ))
 
     plan = ModelPlan(
         layers=layers, backend=backend.name, gamma=gamma, arch=kind,

@@ -39,6 +39,8 @@ from typing import Optional
 
 import numpy as np
 
+from repro.common.spans import span
+
 VALIDATE_MODES = ("off", "fast", "full")
 
 #: the invariant catalog — every class a check can emit, with the contract
@@ -781,6 +783,7 @@ def verify_plan(plan, *, mode: str = "fast", graph=None,
     return v.violations
 
 
+@span("verify")
 def check_plan(plan, *, mode: str = "fast", graph=None, dist=None) -> None:
     """``verify_plan`` that raises :class:`PlanVerificationError`."""
     if _resolve_mode(mode) == "off":

@@ -15,6 +15,8 @@ from typing import Optional
 
 import numpy as np
 
+from repro.common.spans import span
+
 
 @dataclasses.dataclass
 class CSRGraph:
@@ -103,6 +105,7 @@ class CSRGraph:
     def degrees(self) -> np.ndarray:
         return np.diff(self.indptr).astype(np.int64)
 
+    @span("transpose")
     def transpose(self) -> "CSRGraph":
         """CSR of Aᵀ — the paper's CSC view used by the backward pass.
 
@@ -461,6 +464,7 @@ def adaptive_bc(n_cols: int, max_bc: int = 128) -> int:
     return 8
 
 
+@span("bsr_build")
 def csr_to_bsr(csr: CSRGraph, br: int = 8, bc: Optional[int] = None) -> BSRMatrix:
     """CSR→BSR conversion (O(nnz), vectorised).
 

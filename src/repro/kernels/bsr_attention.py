@@ -197,7 +197,8 @@ def bsr_attention_fwd(block_rows, block_cols, first_in_row, last_in_row,
     w = heads * dh
     stat = jax.ShapeDtypeStruct((n_rows_padded, heads), jnp.float32)
     return tuple(windowed_call(
-        _make_fwd_kernel(heads, dh), lead=1, block_rows=block_rows,
+        _make_fwd_kernel(heads, dh), name="bsr_attention_fwd",
+        lead=1, block_rows=block_rows,
         block_cols=block_cols, first_in_row=first_in_row,
         extra_streams=(last_in_row,),
         in_specs=[_block_spec(br, bc), _tile(br, heads),
@@ -258,7 +259,8 @@ def bsr_attention_bwd_row(block_rows, block_cols, first_in_row,
     w = heads * dh
     stat = _tile(br, heads)
     (dc,) = windowed_call(
-        _make_bwd_row_kernel(heads, dh), lead=1, block_rows=block_rows,
+        _make_bwd_row_kernel(heads, dh), name="bsr_attention_bwd_row",
+        lead=1, block_rows=block_rows,
         block_cols=block_cols, first_in_row=first_in_row,
         in_specs=[_block_spec(br, bc), stat, _row_tile(heads, bc),
                   _tile(bc, w, _at_col), _tile(br, w), stat, stat, stat],
@@ -327,7 +329,8 @@ def bsr_attention_bwd_col(block_rows, block_cols, first_in_row,
     w = heads * dh
     dst = _row_tile(heads, bc)
     return tuple(windowed_call(
-        _make_bwd_col_kernel(heads, dh), lead=1, block_rows=block_rows,
+        _make_bwd_col_kernel(heads, dh), name="bsr_attention_bwd_col",
+        lead=1, block_rows=block_rows,
         block_cols=block_cols, first_in_row=first_in_row,
         in_specs=[_block_spec(br, bc), _tile(br, heads), dst,
                   _tile(br, w), _tile(bc, w, _at_col), dst, dst, dst],

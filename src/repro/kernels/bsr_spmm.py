@@ -108,11 +108,14 @@ def open_row(flag, outs: Sequence, inits: Sequence[float],
                 o[...] = c[...]
 
 
-def windowed_call(make_kernel: Callable[[bool], Callable], *, lead: int,
-                  block_rows, block_cols, first_in_row, extra_streams=(),
-                  scalars=(), in_specs, inputs, out_specs, out_shape,
-                  carry_specs, interpret, window=None) -> list:
+def windowed_call(make_kernel: Callable[[bool], Callable], *, name: str,
+                  lead: int, block_rows, block_cols, first_in_row,
+                  extra_streams=(), scalars=(), in_specs, inputs, out_specs,
+                  out_shape, carry_specs, interpret, window=None) -> list:
     """Run one BSR kernel over the block stream, one window per call.
+
+    ``name`` is every call's ``pallas_call`` name: the Mosaic custom
+    call's ``kernel_name``, by which traces and compiled HLO find it.
 
     Scalar-prefetch layout seen by ``make_kernel(resume)``'s kernel and by
     every index map (after the grid indices ``(lead_i, b)``): ``off`` (the
@@ -142,6 +145,7 @@ def windowed_call(make_kernel: Callable[[bool], Callable], *, lead: int,
             out_shape=out_shape,
             input_output_aliases={n_in + i: i for i in range(len(outs))},
             interpret=interpret,
+            name=name,
         )(*sp, *inputs, *outs)
     return outs
 
@@ -212,8 +216,8 @@ def _make_spmm_kernel(*, masked: bool, epilogue: bool, has_self: bool,
     return make
 
 
-def _spmm(block_rows, block_cols, first_in_row, blocks, x, *, n_rows_padded,
-          bf, interpret, window, last_in_row=None, mask=None,
+def _spmm(block_rows, block_cols, first_in_row, blocks, x, *, name,
+          n_rows_padded, bf, interpret, window, last_in_row=None, mask=None,
           self_term=None, bias=None, alpha=None, relu=False):
     """The one BSR SpMM implementation behind all three entry points."""
     _, br, bc = blocks.shape
@@ -241,7 +245,7 @@ def _spmm(block_rows, block_cols, first_in_row, blocks, x, *, n_rows_padded,
                           epilogue=last_in_row is not None,
                           has_self=self_term is not None,
                           has_bias=bias is not None, relu=relu),
-        lead=f // bf, block_rows=block_rows, block_cols=block_cols,
+        name=name, lead=f // bf, block_rows=block_rows, block_cols=block_cols,
         first_in_row=first_in_row,
         extra_streams=() if last_in_row is None else (last_in_row,),
         scalars=(() if self_term is None
@@ -271,8 +275,8 @@ def bsr_spmm(
 ) -> jax.Array:
     """Y = A @ X with A in flattened BSR. Output is float32 [n_rows_padded, F]."""
     return _spmm(block_rows, block_cols, first_in_row, blocks, x,
-                 n_rows_padded=n_rows_padded, bf=bf, interpret=interpret,
-                 window=window)
+                 name="bsr_spmm", n_rows_padded=n_rows_padded, bf=bf,
+                 interpret=interpret, window=window)
 
 
 # ---------------------------------------------------------------------------
@@ -318,8 +322,9 @@ def bsr_spmm_fused_epilogue(
     if bias is not None and bias.shape != (1, f):
         raise ValueError(f"bias must be [1, {f}], got {bias.shape}")
     return _spmm(block_rows, block_cols, first_in_row, blocks, x,
-                 n_rows_padded=n_rows_padded, bf=bf, interpret=interpret,
-                 window=window, last_in_row=last_in_row,
+                 name="bsr_spmm_fused_epilogue", n_rows_padded=n_rows_padded,
+                 bf=bf, interpret=interpret, window=window,
+                 last_in_row=last_in_row,
                  self_term=self_term, bias=bias, alpha=alpha,
                  relu=activation == "relu")
 
@@ -349,5 +354,5 @@ def bsr_spmm_masked(
     if mask.shape != x.shape:
         raise ValueError(f"mask shape {mask.shape} != x shape {x.shape}")
     return _spmm(block_rows, block_cols, first_in_row, blocks, x,
-                 n_rows_padded=n_rows_padded, bf=bf, interpret=interpret,
-                 window=window, mask=mask)
+                 name="bsr_spmm_masked", n_rows_padded=n_rows_padded, bf=bf,
+                 interpret=interpret, window=window, mask=mask)

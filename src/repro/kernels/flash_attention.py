@@ -119,5 +119,6 @@ def flash_attention(
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qf, kf, vf)
     return out[:, :tq].reshape(b, h, tq, d)

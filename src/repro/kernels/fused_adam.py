@@ -92,6 +92,7 @@ def fused_adam(
             jax.ShapeDtypeStruct((rows_padded, _LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_adam",
     )(lr_arr, p2, g2, m2, v2)
 
     def unprep(x, dt):

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.spans import span
 from repro.graph.csr import BSRMatrix, CSRGraph, csr_from_dense, csr_to_bsr
 from repro.kernels.bsr_spmm import (
     bsr_spmm,
@@ -65,6 +66,7 @@ class BSRDevice:
     last_in_row: jax.Array | None = None  # dual of first_in_row (fused epilogue)
 
     @classmethod
+    @span("bsr_upload")
     def from_bsr(cls, bsr: BSRMatrix) -> "BSRDevice":
         return cls(
             block_rows=jnp.asarray(bsr.block_rows),

@@ -125,6 +125,19 @@ class LayerOps:
     fused_epilogue: Optional[Callable] = None
 
 
+def _scoped(name: str, fn: Optional[Callable]) -> Optional[Callable]:
+    """``fn`` run under ``jax.named_scope(name)``, so that its device ops
+    carry the layer's phase in their metadata; None stays None."""
+    if fn is None:
+        return None
+
+    def run(*args, **kwargs):
+        with jax.named_scope(name):
+            return fn(*args, **kwargs)
+
+    return run
+
+
 def apply_layer(config: GNNConfig, layer: dict, x: jax.Array, ops: LayerOps,
                 is_last: bool) -> jax.Array:
     """One layer of any arch, on the given primitives (the shared algebra).
@@ -148,10 +161,13 @@ def apply_layer(config: GNNConfig, layer: dict, x: jax.Array, ops: LayerOps,
     (``tests/test_fused_epilogue.py`` pins both sides).
     """
     kind = config.kind
-    xw = ops.xw
-    mm = xw if xw is not None else (lambda w: x @ w)
+    xw = _scoped("transform", ops.xw)
+    mm = xw if xw is not None else _scoped("transform", lambda w: x @ w)
     res = ops.restrict if ops.restrict is not None else (lambda u: u)
-    fe = ops.fused_epilogue
+    fe = _scoped("aggregate", ops.fused_epilogue)
+    ops = dataclasses.replace(
+        ops, aggregate=_scoped("aggregate", ops.aggregate),
+        gat_attention=_scoped("aggregate", ops.gat_attention))
     relu_ok = config.activation is jax.nn.relu
     post = "relu" if (relu_ok and not is_last) else "none"
     if kind == "GCN":
@@ -283,18 +299,32 @@ class GNNModel:
             x = x[self._perm]
         for i, layer in enumerate(params["layers"]):
             plan_layer = self.plan.layers[i] if i < len(self.plan.layers) else None
-            x = self._layer(layer, x, is_last=(i == n - 1), plan_layer=plan_layer)
+            with jax.named_scope(f"layer{i}"):
+                x = self._layer(layer, x, is_last=(i == n - 1),
+                                plan_layer=plan_layer)
         if self._inv_perm is not None:
             x = x[self._inv_perm]
         return x
 
     def loss_fn(self, params: dict, x: jax.Array, labels: jax.Array,
                 mask: jax.Array) -> jax.Array:
-        logits = self.apply(params, x)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, labels[:, None].astype(jnp.int32), axis=-1)[:, 0]
-        denom = jnp.maximum(mask.sum(), 1)
-        return jnp.where(mask, nll, 0.0).sum() / denom
+        with jax.named_scope("forward"):
+            logits = self.apply(params, x)
+        with jax.named_scope("loss"):
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            nll = -jnp.take_along_axis(logp, labels[:, None].astype(jnp.int32), axis=-1)[:, 0]
+            denom = jnp.maximum(mask.sum(), 1)
+            return jnp.where(mask, nll, 0.0).sum() / denom
+
+    def loss_and_grads(self, params: dict, x: jax.Array, labels: jax.Array,
+                       mask: jax.Array) -> tuple[jax.Array, dict]:
+        """``jax.value_and_grad(loss_fn)``, with the backward pass under
+        the ``backward`` named scope."""
+        loss, pullback = jax.vjp(
+            lambda p: self.loss_fn(p, x, labels, mask), params)
+        with jax.named_scope("backward"):
+            (grads,) = pullback(jnp.ones_like(loss))
+        return loss, grads
 
     def accuracy(self, params: dict, x, labels, mask) -> jax.Array:
         pred = jnp.argmax(self.apply(params, x), axis=-1)

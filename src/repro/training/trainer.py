@@ -34,6 +34,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.backends import DistributedBackend, compose_epilogue, get_backend
 from repro.backends.gather import EdgeListOperand
 from repro.common.jit import jit_hoisted
+from repro.common.spans import span
 from repro.core.aggregate import gather_scatter_aggregate
 from repro.core.halo import DistributedGraph, GhostBufferRing, halo_exchange
 from repro.core.lowering import (
@@ -96,16 +97,18 @@ class FullBatchTrainer:
         # the model's plan operands ride as arguments, not program constants
         @jit_hoisted
         def step(params, opt_state, x, labels, mask):
-            loss, grads = jax.value_and_grad(model.loss_fn)(params, x, labels, mask)
-            params, opt_state = opt.update(grads, opt_state, params)
+            loss, grads = model.loss_and_grads(params, x, labels, mask)
+            with jax.named_scope("optimizer"):
+                params, opt_state = opt.update(grads, opt_state, params)
             return params, opt_state, loss
 
         @jit_hoisted
         def step_guarded(params, opt_state, x, labels, mask, scale, poison):
-            loss, grads = jax.value_and_grad(model.loss_fn)(params, x, labels, mask)
+            loss, grads = model.loss_and_grads(params, x, labels, mask)
             grads = jax.tree_util.tree_map(
                 lambda g: g + poison.astype(g.dtype), grads)
-            p_new, s_new = opt.update(grads, opt_state, params)
+            with jax.named_scope("optimizer"):
+                p_new, s_new = opt.update(grads, opt_state, params)
             return guarded_update(params, opt_state, p_new, s_new, loss, scale)
 
         self._step = step
@@ -124,23 +127,23 @@ class FullBatchTrainer:
         x, labels, mask = jnp.asarray(x), jnp.asarray(labels), jnp.asarray(mask)
         losses, times = [], []
         for epoch in range(start_epoch, epochs):
-            t0 = time.perf_counter()
-            if self.guard is None:
-                params, opt_state, loss = self._step(
-                    params, opt_state, x, labels, mask)
-            else:
-                poison = (self.injector.grad_poison(epoch)
-                          if self.injector is not None else 0.0)
-                params, opt_state, loss, ok = self._step_guarded(
-                    params, opt_state, x, labels, mask,
-                    jnp.float32(self.guard.scale), jnp.float32(poison))
-                action = self.guard.after_step(bool(ok), step=epoch)
-                if action == "rollback" and self.ckpt_dir:
-                    (params, opt_state), _ = restore_checkpoint(
-                        self.ckpt_dir, (params, opt_state))
-            jax.block_until_ready(loss)
-            times.append(time.perf_counter() - t0)
-            losses.append(float(loss))
+            with span("epoch", step=epoch) as timed:
+                if self.guard is None:
+                    params, opt_state, loss = self._step(
+                        params, opt_state, x, labels, mask)
+                else:
+                    poison = (self.injector.grad_poison(epoch)
+                              if self.injector is not None else 0.0)
+                    params, opt_state, loss, ok = self._step_guarded(
+                        params, opt_state, x, labels, mask,
+                        jnp.float32(self.guard.scale), jnp.float32(poison))
+                    action = self.guard.after_step(bool(ok), step=epoch)
+                    if action == "rollback" and self.ckpt_dir:
+                        (params, opt_state), _ = restore_checkpoint(
+                            self.ckpt_dir, (params, opt_state))
+                with span("loss_read"):
+                    losses.append(float(loss))
+            times.append(timed.seconds)
             if self.ckpt_dir and (epoch + 1) % self.ckpt_every == 0:
                 save_checkpoint(self.ckpt_dir, epoch + 1, (params, opt_state),
                                 injector=self.injector)
@@ -478,26 +481,40 @@ class MiniBatchTrainer:
                 "trainer is infer-only (plan.infer_only or no optimizer): "
                 "training is unavailable")
         total, count = 0.0, 0
-        for batch in self.sampler.epoch_batches(
-                self.train_ids, self.features, self.labels_np,
-                rng=self._shuffle_rng):
-            data = self._batch_arrays(batch)
-            if self.guard is None:
-                self.params, self.opt_state, loss = self._step(
-                    self.params, self.opt_state, data)
-            else:
-                poison = (self.injector.grad_poison(self._global_step)
-                          if self.injector is not None else 0.0)
-                self.params, self.opt_state, loss, ok = self._step_guarded(
-                    self.params, self.opt_state, data,
-                    jnp.float32(self.guard.scale), jnp.float32(poison))
-                # rollback (the runner's restore_fn == self.restore) also
-                # rewinds the rng streams, so the replayed epochs redraw
-                # the exact batches the first attempt drew
-                self.guard.after_step(bool(ok), step=self._global_step)
-            self._global_step += 1
-            total += float(loss) * batch.n_seeds
-            count += batch.n_seeds
+        batches = self.sampler.epoch_batches(
+            self.train_ids, self.features, self.labels_np,
+            rng=self._shuffle_rng)
+        n_batches = -(-self.train_ids.shape[0] // self.sampler.batch_size)
+        with span("epoch", step=self._epoch_idx):
+            for _ in range(n_batches):
+                with span("batch"):
+                    with span("sample"):
+                        batch = next(batches)
+                    with span("upload"):
+                        data = self._batch_arrays(batch)
+                    with span("dispatch"):
+                        if self.guard is None:
+                            self.params, self.opt_state, loss = self._step(
+                                self.params, self.opt_state, data)
+                        else:
+                            poison = (self.injector.grad_poison(
+                                self._global_step)
+                                if self.injector is not None else 0.0)
+                            self.params, self.opt_state, loss, ok = \
+                                self._step_guarded(
+                                    self.params, self.opt_state, data,
+                                    jnp.float32(self.guard.scale),
+                                    jnp.float32(poison))
+                    if self.guard is not None:
+                        # rollback (the runner's restore_fn == self.restore)
+                        # also rewinds the rng streams, so the replayed
+                        # epochs redraw the exact batches the first attempt
+                        # drew
+                        self.guard.after_step(bool(ok), step=self._global_step)
+                    self._global_step += 1
+                    with span("loss_read"):
+                        total += float(loss) * batch.n_seeds
+                    count += batch.n_seeds
         return total / max(count, 1)
 
     # -- checkpoint / resume (DESIGN.md §13 RNG-state contract) -------------
@@ -832,20 +849,23 @@ class DistributedGNNTrainer:
         self._data = jax.tree_util.tree_map(dev, data_np)
 
     def train_epoch(self) -> float:
-        t0 = time.perf_counter()
-        if self.guard is None:
-            self.params, self.opt_state, loss = self._step(
-                self.params, self.opt_state, self._data,
-            )
-        else:
-            poison = (self.injector.grad_poison(self._step_idx)
-                      if self.injector is not None else 0.0)
-            self.params, self.opt_state, loss, ok = self._step_guarded(
-                self.params, self.opt_state, self._data,
-                jnp.float32(self.guard.scale), jnp.float32(poison))
-            self.guard.after_step(bool(ok), step=self._step_idx)
-        loss = float(loss)  # blocks: the step's wall time is complete
-        self._feed_heartbeats(time.perf_counter() - t0)
+        with span("epoch", step=self._step_idx) as timed:
+            with span("dispatch"):
+                if self.guard is None:
+                    self.params, self.opt_state, loss = self._step(
+                        self.params, self.opt_state, self._data,
+                    )
+                else:
+                    poison = (self.injector.grad_poison(self._step_idx)
+                              if self.injector is not None else 0.0)
+                    self.params, self.opt_state, loss, ok = self._step_guarded(
+                        self.params, self.opt_state, self._data,
+                        jnp.float32(self.guard.scale), jnp.float32(poison))
+            if self.guard is not None:
+                self.guard.after_step(bool(ok), step=self._step_idx)
+            with span("loss_read"):
+                loss = float(loss)  # blocks: the step's wall time is complete
+        self._feed_heartbeats(timed.seconds)
         self._step_idx += 1
         return loss
 

@@ -9,6 +9,7 @@ topology is described inside a fixture (never at import), and the tests
 skip where no TPU compiler is installed.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +26,7 @@ from repro.kernels.bsr_spmm import (
     bsr_spmm_fused_epilogue,
     bsr_spmm_masked,
 )
+from repro.kernels.flash_attention import flash_attention
 from repro.kernels.fused_adam import fused_adam
 from repro.kernels.ops import feature_tile
 
@@ -132,3 +134,56 @@ def test_bsr_attention_bwd_compiles(shape, kernel, br, bc, n_blocks, heads,
 def test_fused_adam_compiles(shape):
     p = shape((256, 256))
     _compile(lambda *a: fused_adam(*a), p, p, p, p, shape(()))
+
+
+def _attention_fwd(shape, stream, n, heads=8, dh=8):
+    stat = shape((n, heads))
+    rows, cols, first, blocks = stream
+    return (lambda *a: bsr_attention_fwd(*a, n_rows_padded=n, heads=heads,
+                                         dh=dh, window=16),
+            rows, cols, first, first, blocks, stat, stat,
+            shape((n, heads * dh)))
+
+
+def _attention_bwd(kernel):
+    def build(shape, stream, n, heads=8, dh=8):
+        stat, feat = shape((n, heads)), shape((n, heads * dh))
+        return (lambda *a: kernel(*a, n_rows_padded=n, heads=heads, dh=dh,
+                                  window=16),
+                *stream, stat, stat, feat, feat, stat, stat, stat)
+    return build
+
+
+KERNELS = {
+    "bsr_spmm": lambda shape, stream, n: (
+        lambda *a: bsr_spmm(*a, n_rows_padded=n, window=16),
+        *stream, shape((n, 128))),
+    "bsr_spmm_fused_epilogue": lambda shape, stream, n: (
+        lambda *a: bsr_spmm_fused_epilogue(
+            *a, n_rows_padded=n, activation="relu", window=16),
+        *stream[:3], stream[2], stream[3], shape((n, 128)),
+        shape((n, 128)), shape((1, 128)), shape(())),
+    "bsr_spmm_masked": lambda shape, stream, n: (
+        lambda *a: bsr_spmm_masked(*a, n_rows_padded=n, window=16),
+        *stream, shape((n, 128)), shape((n, 128))),
+    "bsr_attention_fwd": _attention_fwd,
+    "bsr_attention_bwd_row": _attention_bwd(bsr_attention_bwd_row),
+    "bsr_attention_bwd_col": _attention_bwd(bsr_attention_bwd_col),
+    "fused_adam": lambda shape, stream, n: (
+        lambda *a: fused_adam(*a), *[shape((256, 256))] * 4, shape(())),
+    "flash_attention": lambda shape, stream, n: (
+        lambda *a: flash_attention(*a), *[shape((1, 2, 256, 128))] * 3),
+}
+
+
+@pytest.mark.parametrize("name", list(KERNELS))
+def test_kernel_lowers_under_its_own_name(shape, name):
+    """Every Mosaic custom call of a kernel carries the kernel's
+    ``kernel_name`` (one call per window: 64 blocks in windows of 16), so
+    traces and compiled HLO find it by that name; none is ``kernel``."""
+    n = 1024
+    stream = _stream(shape, 64, 8, 128)
+    fn, *args = KERNELS[name](shape, stream, n)
+    text = jax.jit(fn).lower(*args).as_text()
+    names = re.findall(r'kernel_name = "([^"]*)"', text)
+    assert names and set(names) == {name}, names
