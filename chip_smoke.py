@@ -114,8 +114,12 @@ def single_chip_phase(ds, arch: str, agg_primitive: str) -> None:
     if default_interpret():
         raise SystemExit("Pallas kernels would run in interpret mode")
     fwd, bwd = plan.graph_op.fwd_operand, plan.graph_op.bwd_operand
-    log(f"{arch}: tile=({fwd.br},{fwd.bc}) blocks A={fwd.blocks.shape[0]} "
-        f"A^T={bwd.blocks.shape[0]}")
+    if fwd.format == "gather":
+        log(f"{arch}: CSR row-gather operands, nnz A={fwd.indices.shape[0]} "
+            f"A^T={bwd.indices.shape[0]}")
+    else:
+        log(f"{arch}: tile=({fwd.br},{fwd.bc}) blocks A={fwd.blocks.shape[0]} "
+            f"A^T={bwd.blocks.shape[0]}")
     t = time.perf_counter()
     compiled.compile_step()
     t_compile = time.perf_counter() - t

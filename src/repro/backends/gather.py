@@ -36,8 +36,9 @@ class GatherBackend(Backend):
     def priority(self) -> int:
         return 10
 
-    def build_spmm_operand(self, csr: CSRGraph, br: int = 8, bc=None):
-        # br/bc are BSR tile hints; the edge-list layout has no blocks
+    def build_spmm_operand(self, csr: CSRGraph, br: int = 8, bc=None,
+                           fmt="bsr"):
+        # br/bc/fmt are BSR hints; the edge-list layout has no blocks
         src, dst = csr.edge_list()
         return EdgeListOperand(
             src=jnp.asarray(src), dst=jnp.asarray(dst),

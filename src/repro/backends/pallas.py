@@ -1,11 +1,14 @@
-"""Pallas (TPU) backend — BSR operands consumed by the fused SpMM kernel.
+"""Pallas (TPU) backend — BSR or CSR operands, each with its own kernels.
 
-The TPU-native lowering: CSR -> BSR once (the MXU consumes dense (BR, BC)
-tiles, the DMA engine moves whole blocks), then every ``spmm`` runs the
-Pallas kernel in ``kernels/bsr_spmm.py``. Off-TPU the kernel still runs via
-the Pallas interpreter — numerically exact but Python-speed, which is why
-``priority()`` drops off-TPU and auto-selection prefers the XLA backend
-there.
+Two operand formats, chosen per graph by fill (``core/layout.py``
+``operand_format``): where nonzeros cluster into blocks, CSR -> BSR once
+(the MXU consumes dense (BR, BC) tiles, the DMA engine moves whole blocks)
+and every ``spmm`` runs ``kernels/bsr_spmm.py``; where they do not, the
+operand stays CSR and every ``spmm`` gathers one source row per nonzero
+(``kernels/csr_gather_spmm.py``). Attention masks are always BSR. Off-TPU
+the kernels run in Pallas interpret mode — numerically exact but slow,
+which is why ``priority()`` drops off-TPU and auto-selection prefers the
+XLA backend there.
 """
 from __future__ import annotations
 
@@ -14,7 +17,7 @@ from typing import Optional
 import jax
 
 from repro.backends.registry import Backend
-from repro.graph.csr import CSRGraph, csr_to_bsr
+from repro.graph.csr import CSRGraph, adaptive_bc, bsr_block_count, csr_to_bsr
 from repro.kernels import ops as kops
 
 
@@ -30,10 +33,19 @@ class PallasBackend(Backend):
         return 100 if jax.default_backend() == "tpu" else 5
 
     def build_spmm_operand(self, csr: CSRGraph, br: int = 8,
-                           bc: Optional[int] = None):
+                           bc: Optional[int] = None, fmt: str = "bsr"):
+        if fmt == "auto":
+            from repro.core.layout import operand_format
+
+            bc_eff = adaptive_bc(csr.n_cols) if bc is None else bc
+            fmt = operand_format(csr.nnz, bsr_block_count(csr, br, bc_eff))
+        if fmt == "gather":
+            return kops.CSRDevice.from_csr(csr)
         return kops.BSRDevice.from_bsr(csr_to_bsr(csr, br=br, bc=bc))
 
     def operand_bytes(self, operand) -> int:
+        if operand.format == "gather":
+            return operand.nbytes
         return int(operand.blocks.nbytes)
 
     def spmm(self, operand, x: jax.Array, *, interpret: Optional[bool] = None) -> jax.Array:
@@ -42,11 +54,15 @@ class PallasBackend(Backend):
     def spmm_fused_epilogue(self, fwd_operand, bwd_operand, *,
                             interpret: Optional[bool] = None,
                             bf: Optional[int] = None):
-        """The native fused kernel: epilogue applied in VMEM at
-        ``last_in_row``; the VJP folds the activation mask into the
-        transposed SpMM (``kernels/bsr_spmm.py:bsr_spmm_masked``).
-        ``bf`` pins the MXU lane tile (autotuned layouts); ``None`` keeps
-        the per-call ``feature_tile`` policy."""
+        """The native fused kernels: epilogue applied in VMEM where a row
+        completes; the VJP folds the activation mask into the transposed
+        SpMM (``bsr_spmm_masked``, ``csr_gather_spmm_masked``). On BSR
+        operands ``bf`` pins the MXU lane tile (autotuned layouts) and
+        ``None`` keeps the per-call ``feature_tile`` policy; CSR operands
+        gather whole rows and take no lane tile."""
+        if fwd_operand.format == "gather":
+            return kops.build_gather_fused_epilogue(fwd_operand, bwd_operand,
+                                                    interpret=interpret)
         return kops.build_fused_epilogue(fwd_operand, bwd_operand, "pallas",
                                          interpret=interpret, bf=bf)
 

@@ -195,11 +195,15 @@ class Backend:
     # -- operand construction (one-time lowering, O(nnz)) --------------------
 
     def build_spmm_operand(self, csr: CSRGraph, br: int = 8,
-                           bc: Optional[int] = None):
+                           bc: Optional[int] = None, fmt: str = "bsr"):
         """Build this backend's sparse operand at the given BSR tile.
         ``bc=None`` is the un-autotuned fallback: adaptive to ``n_cols``
         (``graph.csr.adaptive_bc``) so small graphs stop lane-padding; the
-        lowering pass passes the ``LayoutPlan``'s tile explicitly."""
+        lowering pass passes the ``LayoutPlan``'s tile explicitly.
+        ``fmt`` picks the format where a backend has two (Pallas):
+        ``"bsr"``, ``"gather"`` (CSR row gather), or ``"auto"`` (by fill,
+        ``core/layout.py:operand_format``). Backends with one format
+        ignore it."""
         raise NotImplementedError
 
     def operand_bytes(self, operand) -> int:
@@ -308,8 +312,9 @@ class Backend:
         computes dW = Xᵀ @ dY via the pre-transposed operand. Both O(nnz)
         conversions happen here, once (Alg 1 'DenseToCSR')."""
         x_csr = csr_from_dense(np.asarray(x_np))
-        fwd = self.build_spmm_operand(x_csr, br=br, bc=bc)
-        bwd = self.build_spmm_operand(x_csr.transpose(), br=br, bc=bc)
+        fwd = self.build_spmm_operand(x_csr, br=br, bc=bc, fmt="auto")
+        bwd = self.build_spmm_operand(x_csr.transpose(), br=br, bc=bc,
+                                      fmt=getattr(fwd, "format", "bsr"))
         return self.spmm_transposed_vjp(fwd, bwd, interpret=interpret)
 
 

@@ -26,7 +26,7 @@ class XLABackend(Backend):
         return 60
 
     def build_spmm_operand(self, csr: CSRGraph, br: int = 8,
-                           bc: Optional[int] = None):
+                           bc: Optional[int] = None, fmt: str = "bsr"):
         return kops.BSRDevice.from_bsr(csr_to_bsr(csr, br=br, bc=bc))
 
     def operand_bytes(self, operand) -> int:

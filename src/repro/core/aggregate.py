@@ -6,8 +6,9 @@ Two execution paths, matching the paper's evaluation:
   per-edge source features, scale, segment-sum. Materialises the O(|E|·F)
   edge-message tensor the paper identifies as the dominant memory term.
 * ``make_fused_aggregate`` — Morphling's fused path (Eq. 13): messages are
-  accumulated directly into destination rows by the Pallas BSR SpMM kernel;
-  peak memory is O(|V|·F). The custom VJP backward multiplies by the
+  accumulated directly into destination rows by the Pallas SpMM kernels
+  (BSR, or CSR row gather where the nonzeros do not fill blocks); peak
+  memory is O(|V|·F). The custom VJP backward multiplies by the
   pre-transposed graph (the paper's CSC view, §IV-B.b) so gradients are
   conflict-free by construction.
 
@@ -66,7 +67,7 @@ def gather_scatter_aggregate(
 
 
 # ---------------------------------------------------------------------------
-# Fused: Pallas BSR SpMM with pre-transposed backward
+# Fused: Pallas SpMM (BSR or CSR row gather) with pre-transposed backward
 # ---------------------------------------------------------------------------
 
 @dataclasses.dataclass
@@ -104,6 +105,15 @@ class FusedGraphOp:
         )
 
 
+def _operand_pair(backend: Backend, weighted: CSRGraph, br: int,
+                  bc: int | None, fmt: str):
+    """(A, Aᵀ) on ``backend``, both in the format A takes."""
+    fwd = backend.build_spmm_operand(weighted, br=br, bc=bc, fmt=fmt)
+    bwd = backend.build_spmm_operand(weighted.transpose(), br=br, bc=bc,
+                                     fmt=getattr(fwd, "format", "bsr"))
+    return fwd, bwd
+
+
 @span("graph_op")
 def make_fused_aggregate(
     graph: CSRGraph,
@@ -114,6 +124,7 @@ def make_fused_aggregate(
     engine: "str | Backend | None" = None,  # registry name; None = auto-select
     bf: int | None = None,
     build_attention: bool = False,
+    fmt: str = "auto",
 ) -> FusedGraphOp:
     """One-time lowering: weight the adjacency, build the forward/backward
     operand pair on the selected backend, return a differentiable fused
@@ -124,7 +135,9 @@ def make_fused_aggregate(
     ``build_attention`` additionally binds the backend's fused
     ``spmm_attention`` over the same pair (attention ignores the edge
     weights — the nonzero pattern is the adjacency mask, so the weighted
-    operands double as attention masks at zero extra memory)."""
+    operands double as attention masks at zero extra memory); it needs
+    BSR operands. ``fmt`` is A's operand format on backends that have two
+    (``"bsr"`` | ``"gather"`` | ``"auto"``: by fill); Aᵀ takes A's."""
     backend = select_backend(engine)
     weighted = _weighted_graph(graph, aggregation)
     src_np, dst_np = weighted.edge_list()
@@ -142,9 +155,7 @@ def make_fused_aggregate(
         agg_attention = None
         fwd = bwd = None
         if build_attention:
-            fwd = backend.build_spmm_operand(weighted, br=br, bc=bc)
-            bwd = backend.build_spmm_operand(weighted.transpose(), br=br,
-                                             bc=bc)
+            fwd, bwd = _operand_pair(backend, weighted, br, bc, "bsr")
             agg_attention = backend.spmm_attention(fwd, bwd,
                                                    interpret=interpret, bf=bf)
 
@@ -157,8 +168,8 @@ def make_fused_aggregate(
         )
 
     # (A, Aᵀ) operands — the paper's CSR-forward / CSC-backward pairing
-    fwd = backend.build_spmm_operand(weighted, br=br, bc=bc)
-    bwd = backend.build_spmm_operand(weighted.transpose(), br=br, bc=bc)
+    fwd, bwd = _operand_pair(backend, weighted, br, bc,
+                             "bsr" if build_attention else fmt)
     agg = backend.spmm_transposed_vjp(fwd, bwd, interpret=interpret)
     agg_epilogue = backend.spmm_fused_epilogue(fwd, bwd, interpret=interpret,
                                                bf=bf)

@@ -55,6 +55,16 @@ TILE_CANDIDATES = ((8, 16), (8, 32), (8, 64), (8, 128), (16, 32), (16, 64))
 #: whose per-block overhead would dominate
 BLOCK_OVERHEAD = 4096.0
 
+#: fill (nonzeros per block at the planned tile) below which a Pallas
+#: aggregation operand stays CSR and runs the row-gather SpMM
+#: (``kernels/csr_gather_spmm.py``). Measured on a TPU v5e: a BSR grid step
+#: costs ~0.3 µs per block per 128-lane tile whatever its fill, a row
+#: gather ~25 ns per nonzero at 40 to 256 lanes (PERF.md §5); 0.3 µs /
+#: 25 ns = 12 at one lane tile. Wider rows only move the crossover up (BSR
+#: pays per lane tile, the gather per row), so one tile is the
+#: conservative ratio.
+GATHER_FILL = 12.0
+
 #: timed candidates since import — the cache-determinism proof observable
 #: (a cache hit leaves this untouched)
 _MEASURE_CALLS = 0
@@ -102,6 +112,12 @@ class LayoutPlan:
         if self.n_blocks:
             line += f" blocks={self.n_blocks} waste={self.padding_waste:.1%}"
         return f"{line} [{self.source}]"
+
+
+def operand_format(nnz: int, n_blocks: int) -> str:
+    """``"gather"`` (CSR row gather) or ``"bsr"`` for a Pallas operand with
+    ``nnz`` nonzeros in ``n_blocks`` blocks at its tile (``GATHER_FILL``)."""
+    return "gather" if nnz < GATHER_FILL * max(n_blocks, 1) else "bsr"
 
 
 def default_layout(graph: CSRGraph, br: Optional[int] = None,

@@ -35,12 +35,14 @@ import jax
 import numpy as np
 
 from repro.backends import Backend, select_backend
-from repro.common.spans import span
+from repro.common.spans import count, span
 from repro.core.aggregate import FusedGraphOp, _weighted_graph, make_fused_aggregate
 from repro.core.layout import (
+    GATHER_FILL,
     LayoutPlan,
     _select_order,
     default_layout,
+    operand_format,
     plan_layout,
 )
 from repro.core.sparsity import (
@@ -170,6 +172,9 @@ class LayerPlan:
     # the layout the layer's sparse operands were built at (shared across a
     # plan's layers); None = pre-layout-stage plans
     layout: Optional[LayoutPlan] = None
+    # format of the aggregation operands: "gather" (CSR row gather) |
+    # "bsr"; "" where the aggregation has no matmul operand
+    operand: str = ""
 
     def describe(self) -> str:
         d = self.decision
@@ -179,6 +184,8 @@ class LayerPlan:
             f"agg={self.agg_primitive}  "
             f"s={d.sparsity:.3f} tau={d.threshold:.2f} mode={d.mode}"
         )
+        if self.operand:
+            line += f"  operand={self.operand}"
         if self.epilogue is not None:
             line += f"  epilogue[{self.epilogue.describe()}]"
         if self.attention is not None:
@@ -901,9 +908,21 @@ def lower(
         features_exec = None if features is None else np.asarray(features)
     n_nodes = graph_exec.n_rows
 
+    # the aggregation operand's format, by the fill the layout counted
+    # (backends with one format ignore it; attention always takes BSR)
+    fmt = (operand_format(graph_exec.nnz, lp.n_blocks) if lp.n_blocks
+           else "auto")
     graph_op = make_fused_aggregate(
         graph_exec, agg, br=lp.br, bc=lp.bc, interpret=interpret,
-        engine=backend, bf=lp.bf or None, build_attention=emit_attn)
+        engine=backend, bf=lp.bf or None, build_attention=emit_attn, fmt=fmt)
+    operand = getattr(graph_op.fwd_operand, "format", "")
+    operand_note = f"{operand} operand" if operand else ""
+    if operand and emit_attn:
+        operand_note += ": attention masks are blocks"
+    elif operand and lp.n_blocks:
+        operand_note += (
+            f": {graph_exec.nnz / lp.n_blocks:.2f} nonzeros per "
+            f"{lp.br}x{lp.bc} block, pallas gathers below {GATHER_FILL:g}")
     # operands are built — drop the layout's host-side graph copy so the
     # plan (held for the model's lifetime) doesn't duplicate the graph
     if lp.reordered_graph is not None:
@@ -930,6 +949,10 @@ def lower(
 
     layers: list[LayerPlan] = []
     with span("decide"):
+        formats = [getattr(op, "format", None)
+                   for op in (graph_op.fwd_operand, graph_op.bwd_operand)]
+        for name in ("gather", "bsr"):
+            count(f"operand_{name}", formats.count(name))
         for i in range(config.n_layers):
             d_in, d_out = dims[i], dims[i + 1]
             if i == 0:
@@ -984,12 +1007,14 @@ def lower(
                 attention = _attention_binding(config.gat_heads, d_out,
                                                attn_bound)
 
+            if operand_note:
+                note = f"{note}; {operand_note}" if note else operand_note
             layers.append(LayerPlan(
                 index=i, op_kind=kind, d_in=d_in, d_out=d_out,
                 feature_path=path, primitive=primitive,
                 agg_primitive=agg_primitive, decision=decision,
                 sparse_xw=sparse_xw, note=note, epilogue=epilogue,
-                attention=attention, layout=lp,
+                attention=attention, layout=lp, operand=operand,
             ))
 
     plan = ModelPlan(

@@ -19,7 +19,8 @@ It is invoked from ``lower`` / ``lower_distributed`` / ``lower_sampled``
 * ``"fast"`` (the default) — metadata and index-structure checks only:
   O(n_blocks) over the index arrays, O(n) over permutations. No block
   *values* are read, so nothing large crosses the device boundary and
-  lowering wall-time grows by well under 5 %.
+  lowering wall-time grows by well under 5 %. A CSR (row-gather) operand
+  is O(nnz) and is read whole: structure, finite values, per-row mass.
 * ``"full"`` — everything in fast, plus value-level checks: zeroed
   padding, finite blocks, per-block-row mass agreement between operand
   and exec graph, interior+boundary reconstruction of the bulk operand,
@@ -58,13 +59,20 @@ INVARIANT_CATALOG = {
     "bsr.row_coverage": "every block-row covered (explicit zero blocks)",
     "bsr.padding_zero": "row/col overhang regions of edge blocks are zero",
     "bsr.finite": "block values are finite (no NaN/Inf in operands)",
+    # CSR structure (the row-gather operand, kernels.ops.CSRDevice)
+    "csr.indptr": "indptr int32 of n_rows + 1 entries, non-decreasing "
+                  "from 0 to nnz",
+    "csr.indices_in_range": "column indices int32 within [0, n_cols)",
+    "csr.indices_sorted": "column indices non-decreasing within each row",
+    "csr.row_ids": "the row id stored for each nonzero agrees with indptr",
+    "csr.finite": "values are finite (no NaN/Inf in operands)",
     # PR-5 permutation contract
     "perm.bijection": "perm and inv_perm are permutations of [0, n)",
     "perm.inverse": "perm[inv_perm] == identity (mutually inverse)",
     "layout.tile_match": "operands built at the layout's (br, bc) tile",
     "layout.graph_match": "operand row space matches the exec graph",
-    "layout.operand_rows": "per-block-row operand mass matches the "
-                           "aggregation-weighted exec graph",
+    "layout.operand_rows": "per-row (BSR: per-block-row) operand mass "
+                           "matches the aggregation-weighted exec graph",
     # PR-7 split-phase rules
     "split.interior_no_ghost": "interior operand never reads a ghost column",
     "split.reconstruction": "interior + boundary blocks reconstruct the "
@@ -392,28 +400,43 @@ def _check_layout(v: _Ctx, lp, n_exec_rows: Optional[int]) -> None:
                f"{n_exec_rows} rows")
 
 
+def _row_sums(indptr: np.ndarray, values: np.ndarray) -> np.ndarray:
+    rows = np.repeat(np.arange(indptr.shape[0] - 1), np.diff(indptr))
+    return np.bincount(rows, weights=values.astype(np.float64),
+                       minlength=indptr.shape[0] - 1)
+
+
+def _weighted_row_sums(graph, aggregation, transposed: bool):
+    """Per-row mass of the aggregation-weighted exec graph (of its
+    transpose for the backward operand); None where operands keep raw
+    weights (max: attention masks) or the weighting does not apply."""
+    from repro.core.aggregate import _weighted_graph
+
+    if aggregation == "max":
+        return None
+    try:
+        weighted = _weighted_graph(graph, aggregation)
+    except (ValueError, AssertionError):
+        return None
+    if transposed:  # rows of Aᵀ are the columns of A
+        return np.bincount(weighted.indices,
+                           weights=weighted.data.astype(np.float64),
+                           minlength=weighted.n_cols)
+    return _row_sums(weighted.indptr, weighted.data)
+
+
 def _check_operand_rows(v: _Ctx, operand: str, dev, graph, aggregation,
                         transposed: bool) -> None:
     """Full mode: per-block-row mass of the operand must equal the
     aggregation-weighted exec graph's — catches operands built on the
     wrong (un-permuted, mis-weighted) graph even when totals agree."""
-    from repro.core.aggregate import _weighted_graph
-
-    if aggregation == "max":
-        return  # max operands (attention masks) keep raw weights
-    try:
-        weighted = _weighted_graph(graph, aggregation)
-    except (ValueError, AssertionError):
+    row_sums = _weighted_row_sums(graph, aggregation, transposed)
+    if row_sums is None:
         return
-    csr = weighted.transpose() if transposed else weighted
-    row_sums = np.zeros(csr.n_rows, dtype=np.float64)
-    reps = np.diff(csr.indptr)
-    np.add.at(row_sums, np.repeat(np.arange(csr.n_rows), reps),
-              csr.data.astype(np.float64))
     br = int(dev.br)
-    nrb = -(-csr.n_rows // br)
+    nrb = -(-row_sums.shape[0] // br)
     want = np.zeros(nrb, dtype=np.float64)
-    np.add.at(want, np.arange(csr.n_rows) // br, row_sums)
+    np.add.at(want, np.arange(row_sums.shape[0]) // br, row_sums)
     got = np.zeros(nrb, dtype=np.float64)
     rows = _np(dev.block_rows).astype(np.int64)
     blocks = _np(dev.blocks).astype(np.float64)
@@ -423,6 +446,63 @@ def _check_operand_rows(v: _Ctx, operand: str, dev, graph, aggregation,
         bad = int(np.argmax(np.abs(got - want)))
         v.flag(-1, operand, "layout.operand_rows",
                f"block-row {bad} mass {got[bad]:.6g} != weighted graph's "
+               f"{want[bad]:.6g} — operand not built on the exec graph?")
+
+
+def _check_csr_device(v: _Ctx, operand: str, dev, graph, aggregation,
+                      transposed: bool) -> None:
+    """Checks for a ``kernels.ops.CSRDevice`` (the row-gather operand). It
+    is O(nnz) and the kernel trusts every word of it, so fast mode reads
+    it whole: pointers, column range and order, finite values, and — given
+    the exec graph — per-row mass against the weighted graph."""
+    h = dev.host_view()
+    indptr, indices, values = h["indptr"], h["indices"], h["values"]
+    rows = h["rows"]
+    n_rows, n_cols, nnz = int(dev.n_rows), int(dev.n_cols), indices.shape[0]
+    if values.dtype != np.float32:
+        v.flag(-1, operand, "binding.operand_dtype",
+               f"values dtype {values.dtype}, expected float32")
+    if indices.dtype != np.int32:
+        v.flag(-1, operand, "csr.indices_in_range",
+               f"indices dtype {indices.dtype}, expected int32")
+    elif nnz and (int(indices.min()) < 0 or int(indices.max()) >= n_cols):
+        v.flag(-1, operand, "csr.indices_in_range",
+               f"column indices span [{int(indices.min())}, "
+               f"{int(indices.max())}], valid range [0, {n_cols})")
+    if not np.isfinite(values).all():
+        v.flag(-1, operand, "csr.finite",
+               f"{int((~np.isfinite(values)).sum())} non-finite value(s)")
+    p = indptr.astype(np.int64)
+    if (indptr.dtype != np.int32 or p.shape[0] != n_rows + 1
+            or p[0] != 0 or p[-1] != nnz or (p[1:] < p[:-1]).any()):
+        bad = np.flatnonzero(p[1:] < p[:-1])
+        where = f", decreases at row {int(bad[0])}" if bad.size else ""
+        v.flag(-1, operand, "csr.indptr",
+               f"indptr {indptr.dtype}[{p.shape[0]}] spans "
+               f"[{int(p[0])}, {int(p[-1])}] for {n_rows} rows and "
+               f"{nnz} nonzeros{where}")
+        return  # rows are undefined: order and mass cannot be read
+    if rows.dtype != np.int32 or not np.array_equal(
+            rows, np.repeat(np.arange(n_rows), np.diff(p))):
+        v.flag(-1, operand, "csr.row_ids",
+               f"row ids {rows.dtype}[{rows.shape[0]}] disagree with indptr")
+    row_start = np.zeros(nnz, dtype=bool)
+    row_start[p[:-1][p[:-1] < nnz]] = True
+    down = np.flatnonzero((indices[1:] < indices[:-1]) & ~row_start[1:]) + 1
+    if down.size:
+        row = int(np.searchsorted(p, down[0], side="right")) - 1
+        v.flag(-1, operand, "csr.indices_sorted",
+               f"column {int(indices[down[0]])} after "
+               f"{int(indices[down[0] - 1])} in row {row}")
+    want = (None if graph is None
+            else _weighted_row_sums(graph, aggregation, transposed))
+    if want is None or want.shape[0] != n_rows:
+        return
+    got = _row_sums(p, values)
+    if not np.allclose(got, want, rtol=1e-4, atol=1e-5):
+        bad = int(np.argmax(np.abs(got - want)))
+        v.flag(-1, operand, "layout.operand_rows",
+               f"row {bad} mass {got[bad]:.6g} != weighted graph's "
                f"{want[bad]:.6g} — operand not built on the exec graph?")
 
 
@@ -483,6 +563,10 @@ def _verify_model_plan(v: _Ctx, plan, graph) -> None:
         return
     for name, dev, transposed in (("graph_op.fwd", gop.fwd_operand, False),
                                   ("graph_op.bwd", gop.bwd_operand, True)):
+        if getattr(dev, "format", None) == "gather":
+            _check_csr_device(v, name, dev, graph, plan.aggregation,
+                              transposed)
+            continue
         if dev is None or not hasattr(dev, "block_rows"):
             continue
         _check_bsr_device(

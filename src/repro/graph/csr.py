@@ -135,15 +135,15 @@ class CSRGraph:
             out[row, self.indices[s:e]] += self.data[s:e]
         return out
 
+    def _rows(self) -> np.ndarray:
+        """The row of each nonzero."""
+        return np.repeat(np.arange(self.n_rows), np.diff(self.indptr))
+
     def row_normalized(self) -> "CSRGraph":
         """D⁻¹A — mean aggregation weights."""
         deg = np.maximum(self.degrees(), 1).astype(self.data.dtype)
         scale = 1.0 / deg
-        data = self.data.copy()
-        for row in range(self.n_rows):
-            s, e = self.indptr[row], self.indptr[row + 1]
-            data[s:e] *= scale[row]
-        return dataclasses.replace(self, data=data)
+        return dataclasses.replace(self, data=self.data * scale[self._rows()])
 
     def sym_normalized(self) -> "CSRGraph":
         """D^(-1/2) A D^(-1/2) — GCN aggregation weights (square graphs)."""
@@ -152,11 +152,8 @@ class CSRGraph:
         deg_in = self.degrees()
         d_in = 1.0 / np.sqrt(np.maximum(deg_in, 1)).astype(self.data.dtype)
         d_out = 1.0 / np.sqrt(np.maximum(deg_out, 1)).astype(self.data.dtype)
-        data = self.data.copy()
-        for row in range(self.n_rows):
-            s, e = self.indptr[row], self.indptr[row + 1]
-            data[s:e] *= d_in[row] * d_out[self.indices[s:e]]
-        return dataclasses.replace(self, data=data)
+        return dataclasses.replace(
+            self, data=self.data * (d_in[self._rows()] * d_out[self.indices]))
 
     def edge_list(self) -> tuple[np.ndarray, np.ndarray]:
         """(src=col, dst=row) arrays — gather-scatter baseline format."""

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+from typing import ClassVar
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +27,11 @@ from repro.kernels.bsr_attention import (
     bsr_attention_bwd_col,
     bsr_attention_bwd_row,
     bsr_attention_fwd,
+)
+from repro.kernels.csr_gather_spmm import (
+    csr_gather_spmm,
+    csr_gather_spmm_fused_epilogue,
+    csr_gather_spmm_masked,
 )
 from repro.kernels.fused_adam import fused_adam  # re-export
 
@@ -64,6 +70,8 @@ class BSRDevice:
     br: int
     bc: int
     last_in_row: jax.Array | None = None  # dual of first_in_row (fused epilogue)
+
+    format: ClassVar[str] = "bsr"
 
     @classmethod
     @span("bsr_upload")
@@ -130,6 +138,54 @@ class BSRDevice:
         if self.n_rows != self.n_rows_padded:
             y = y[: self.n_rows]
         return y
+
+
+@dataclasses.dataclass
+class CSRDevice:
+    """Device-resident CSR: the row-gather SpMM's operand
+    (``kernels/csr_gather_spmm.py``), for graphs whose nonzeros do not fill
+    BSR blocks. O(nnz) bytes: no blocks, no padding."""
+
+    indptr: jax.Array   # [n_rows + 1] int32
+    indices: jax.Array  # [nnz] int32, sorted within each row
+    rows: jax.Array     # [nnz] int32, the row of each nonzero
+    values: jax.Array   # [nnz] float32
+    n_rows: int
+    n_cols: int
+
+    format: ClassVar[str] = "gather"
+
+    @classmethod
+    @span("csr_build")
+    def from_csr(cls, csr: CSRGraph) -> "CSRDevice":
+        rows = np.repeat(np.arange(csr.n_rows, dtype=np.int32),
+                         np.diff(csr.indptr))
+        return cls(indptr=jnp.asarray(csr.indptr, jnp.int32),
+                   indices=jnp.asarray(csr.indices, jnp.int32),
+                   rows=jnp.asarray(rows),
+                   values=jnp.asarray(csr.data, jnp.float32),
+                   n_rows=csr.n_rows, n_cols=csr.n_cols)
+
+    @property
+    def arrays(self) -> tuple:
+        """(indptr, indices, rows, values): the kernels' leading arguments."""
+        return self.indptr, self.indices, self.rows, self.values
+
+    @property
+    def nbytes(self) -> int:
+        return sum(int(a.nbytes) for a in self.arrays)
+
+    def host_view(self) -> dict:
+        """One-shot host copy of the four arrays (for ``core.verify``)."""
+        host = jax.device_get(dict(zip(("indptr", "indices", "rows",
+                                        "values"), self.arrays)))
+        return {k: np.asarray(v) for k, v in host.items()}
+
+    def matmul(self, x: jax.Array, interpret: bool | None = None) -> jax.Array:
+        """Y = A @ X on unpadded x [n_cols, F] -> [n_rows, F]."""
+        interpret = default_interpret() if interpret is None else interpret
+        return csr_gather_spmm(*self.arrays, x, n_rows=self.n_rows,
+                               interpret=interpret)
 
 
 def build_bsr_pair(graph: CSRGraph, br: int = 8,
@@ -345,6 +401,13 @@ def _fused_pair_bwd(geom, bf, interpret, inner, activation, res, dy):
         dx = dx[:n_cols_padded]
     elif n_back_padded < n_cols_padded:
         dx = jnp.pad(dx, ((0, n_cols_padded - n_back_padded), (0, 0)))
+    return (_zero_cotangents(fwd_arrays), _zero_cotangents(bwd_arrays),
+            dx, *_epilogue_cotangents(dz, self_term, bias, alpha))
+
+
+def _epilogue_cotangents(dz, self_term, bias, alpha):
+    """(dself, dbias, dalpha) of ``alpha·self_term + bias`` given the
+    (masked) cotangent dz: a scale and two reductions of the same stream."""
     dself = dalpha = None
     if self_term is not None:
         a = jnp.asarray(alpha, jnp.float32)
@@ -352,11 +415,87 @@ def _fused_pair_bwd(geom, bf, interpret, inner, activation, res, dy):
         dalpha = jnp.vdot(dz, self_term.astype(jnp.float32)).astype(
             jnp.result_type(alpha))
     dbias = None if bias is None else dz.sum(axis=0, keepdims=True)
-    return (_zero_cotangents(fwd_arrays), _zero_cotangents(bwd_arrays),
-            dx, dself, dbias, dalpha)
+    return dself, dbias, dalpha
 
 
 bsr_spmm_fused_pair.defvjp(_fused_pair_fwd, _fused_pair_bwd)
+
+
+# ---------------------------------------------------------------------------
+# Fused-epilogue pair over CSR operands: the row-gather kernels
+# ---------------------------------------------------------------------------
+
+def _gather_fused(fwd_arrays, x, self_term, bias, alpha, n_rows, interpret,
+                  activation):
+    """(y, mask|None) of the fused-epilogue gather kernel."""
+    interpret = default_interpret() if interpret is None else interpret
+    out = csr_gather_spmm_fused_epilogue(
+        *fwd_arrays, x, self_term, bias, alpha, n_rows=n_rows,
+        activation=activation, interpret=interpret)
+    return out if activation == "relu" else (out, None)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def csr_spmm_fused_pair(fwd_arrays, bwd_arrays, x, self_term, bias, alpha,
+                        geom, interpret=None, activation="none"):
+    """Y = act(A @ X + alpha * self_term + bias) over a CSR pair.
+
+    The row-gather sibling of ``bsr_spmm_fused_pair``: ``fwd_arrays`` and
+    ``bwd_arrays`` are the (indptr, indices, rows, values) of A and Aᵀ,
+    ``geom`` is A's ``(n_rows, n_cols)``. Operands are unpadded: x [n_cols, F],
+    self_term [n_rows, F], bias [1, F]. The VJP gathers rows of the masked
+    cotangent mask ⊙ dY along Aᵀ (``csr_gather_spmm_masked``).
+    """
+    y, _ = _gather_fused(fwd_arrays, x, self_term, bias, alpha, geom[0],
+                         interpret, activation)
+    return y
+
+
+def _csr_fused_fwd(fwd_arrays, bwd_arrays, x, self_term, bias, alpha, geom,
+                   interpret, activation):
+    y, mask = _gather_fused(fwd_arrays, x, self_term, bias, alpha, geom[0],
+                            interpret, activation)
+    return y, (fwd_arrays, bwd_arrays, mask, self_term, bias, alpha)
+
+
+def _csr_fused_bwd(geom, interpret, activation, res, dy):
+    fwd_arrays, bwd_arrays, mask, self_term, bias, alpha = res
+    interpret = default_interpret() if interpret is None else interpret
+    dy = dy.astype(jnp.float32)
+    if activation == "relu":
+        dz = dy * mask
+        dx = csr_gather_spmm_masked(*bwd_arrays, dy, mask, n_rows=geom[1],
+                                    interpret=interpret)
+    else:
+        dz = dy
+        dx = csr_gather_spmm(*bwd_arrays, dy, n_rows=geom[1],
+                             interpret=interpret)
+    return (_zero_cotangents(fwd_arrays), _zero_cotangents(bwd_arrays),
+            dx, *_epilogue_cotangents(dz, self_term, bias, alpha))
+
+
+csr_spmm_fused_pair.defvjp(_csr_fused_fwd, _csr_fused_bwd)
+
+
+def build_gather_fused_epilogue(fwd: "CSRDevice", bwd: "CSRDevice",
+                                interpret: bool | None = None):
+    """Differentiable fused-epilogue closure over a (A, Aᵀ) CSRDevice pair,
+    with ``build_fused_epilogue``'s calling convention. Rows are gathered
+    whole, so there is no lane tile to choose."""
+    geom = (fwd.n_rows, fwd.n_cols)
+
+    def fused(u, self_term=None, bias=None, alpha=None, activation="none"):
+        s = a = None
+        if self_term is not None:
+            s = self_term.astype(jnp.float32)
+            a = jnp.float32(1.0) if alpha is None else alpha
+        b = None if bias is None else bias.reshape(1, -1).astype(jnp.float32)
+        y = csr_spmm_fused_pair(fwd.arrays, bwd.arrays,
+                                u.astype(jnp.float32), s, b, a, geom,
+                                interpret, activation)
+        return y.astype(u.dtype)
+
+    return fused
 
 
 def build_fused_epilogue(fwd: "BSRDevice", bwd: "BSRDevice", inner: str,

@@ -1,6 +1,7 @@
 """Mosaic compiles every Pallas kernel of the main path for a described TPU
 v5e, at ogbn-arxiv's published size (169,343 nodes; 917,090 blocks at the
-(8, 128) tile, 1,081,262 at (8, 16)).
+(8, 128) tile, 1,081,262 at (8, 16); 1,320,039 nonzeros for the CSR
+row-gather kernels).
 
 Nothing runs: each test lowers and compiles against the topology only, so
 it catches what interpret mode cannot — SMEM overflow from scalar-prefetched
@@ -26,11 +27,17 @@ from repro.kernels.bsr_spmm import (
     bsr_spmm_fused_epilogue,
     bsr_spmm_masked,
 )
+from repro.kernels.csr_gather_spmm import (
+    csr_gather_spmm,
+    csr_gather_spmm_fused_epilogue,
+    csr_gather_spmm_masked,
+)
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.fused_adam import fused_adam
 from repro.kernels.ops import feature_tile
 
 N_PAD = 169_344  # ogbn-arxiv's 169,343 nodes padded to the tile
+N_NODES, NNZ = 169_343, 1_320_039  # ogbn-arxiv with self loops
 TILES = [pytest.param(8, 16, 1_081_262, id="8x16"),
          pytest.param(8, 128, 917_090, id="8x128")]
 
@@ -101,6 +108,40 @@ def test_bsr_spmm_masked_compiles(shape, br, bc, n_blocks, f):
     _compile(lambda *a: bsr_spmm_masked(*a, n_rows_padded=N_PAD, bf=bf),
              rows, cols, first, blocks, shape((N_PAD, f)),
              shape((N_PAD, f)))
+
+
+def _csr(shape, n=N_NODES, nnz=NNZ):
+    """(indptr, indices, rows, values) of an n-row CSR."""
+    idx = shape((nnz,), jnp.int32)
+    return shape((n + 1,), jnp.int32), idx, idx, shape((nnz,))
+
+
+GATHER = {
+    "csr_gather_spmm": lambda shape, n, f: (
+        lambda *a: csr_gather_spmm(*a, n_rows=n), shape((n, f))),
+    "csr_gather_spmm_fused_epilogue": lambda shape, n, f: (
+        lambda *a: csr_gather_spmm_fused_epilogue(
+            *a, n_rows=n, activation="relu"),
+        shape((n, f)), shape((n, f)), shape((f,)), shape(())),
+    "csr_gather_spmm_masked": lambda shape, n, f: (
+        lambda *a: csr_gather_spmm_masked(*a, n_rows=n),
+        shape((n, f)), shape((n, f))),
+}
+
+
+def _gather(shape, name, n, nnz, f):
+    """``(fn, *args)`` of one row-gather kernel over a CSR of ``n`` rows."""
+    fn, *rest = GATHER[name](shape, n, f)
+    return (fn, *_csr(shape, n, nnz), *rest)
+
+
+@pytest.mark.parametrize("f", [256, 40])
+@pytest.mark.parametrize("name", list(GATHER))
+def test_csr_gather_compiles(shape, name, f):
+    """The row-gather kernels at arxiv's size: SMEM holds one window of
+    row pointers and two chunks of indices whatever nnz is, and the gather
+    buffer two chunks of full-width rows in VMEM."""
+    _compile(*_gather(shape, name, N_NODES, NNZ, f))
 
 
 HEADS = [pytest.param(4, 64, id="4x64"), pytest.param(8, 8, id="8x8")]
@@ -176,11 +217,18 @@ KERNELS = {
 }
 
 
+KERNELS.update({
+    name: lambda shape, stream, n, _name=name: _gather(shape, _name, n, 4096,
+                                                       128)
+    for name in GATHER})
+
+
 @pytest.mark.parametrize("name", list(KERNELS))
 def test_kernel_lowers_under_its_own_name(shape, name):
     """Every Mosaic custom call of a kernel carries the kernel's
-    ``kernel_name`` (one call per window: 64 blocks in windows of 16), so
-    traces and compiled HLO find it by that name; none is ``kernel``."""
+    ``kernel_name`` (BSR: one call per window, 64 blocks in windows of 16;
+    CSR: one call), so traces and compiled HLO find it by that name; none
+    is ``kernel``."""
     n = 1024
     stream = _stream(shape, 64, 8, 128)
     fn, *args = KERNELS[name](shape, stream, n)
