@@ -5,7 +5,9 @@ Two operand formats, chosen per graph by fill (``core/layout.py``
 (the MXU consumes dense (BR, BC) tiles, the DMA engine moves whole blocks)
 and every ``spmm`` runs ``kernels/bsr_spmm.py``; where they do not, the
 operand stays CSR and every ``spmm`` gathers one source row per nonzero
-(``kernels/csr_gather_spmm.py``). Attention masks are always BSR. Off-TPU
+(``kernels/csr_gather_spmm.py``); attention follows the same rule, with
+its own kernels for each format (``kernels/bsr_attention.py``,
+``kernels/csr_gather_attention.py``). Off-TPU
 the kernels run in Pallas interpret mode — numerically exact but slow,
 which is why ``priority()`` drops off-TPU and auto-selection prefers the
 XLA backend there.
@@ -69,9 +71,13 @@ class PallasBackend(Backend):
     def sparse_mha(self, fwd_operand, bwd_operand, *,
                    interpret: Optional[bool] = None,
                    bf: Optional[int] = None):
-        """The native fused attention kernel (DESIGN.md §10): online segment
-        softmax + aggregation in one VMEM pass, recompute VJP from the saved
-        per-row (max, denominator) stats. ``bf`` tiles the per-head lane dim
-        when the cached layout asks for it."""
+        """The native fused attention kernels (DESIGN.md §10): online
+        segment softmax + aggregation in one VMEM pass, recompute VJP from
+        the saved per-row (max, denominator) stats. On BSR operands ``bf``
+        tiles the per-head lane dim when the cached layout asks for it; CSR
+        operands gather whole rows (``csr_gather_attention``)."""
+        if fwd_operand.format == "gather":
+            return kops.build_gather_mha(fwd_operand, bwd_operand,
+                                         interpret=interpret)
         return kops.build_sparse_mha(fwd_operand, bwd_operand, "pallas",
                                      interpret=interpret, bf=bf)

@@ -135,8 +135,8 @@ def make_fused_aggregate(
     ``build_attention`` additionally binds the backend's fused
     ``spmm_attention`` over the same pair (attention ignores the edge
     weights — the nonzero pattern is the adjacency mask, so the weighted
-    operands double as attention masks at zero extra memory); it needs
-    BSR operands. ``fmt`` is A's operand format on backends that have two
+    operands double as attention masks at zero extra memory), in either
+    format. ``fmt`` is A's operand format on backends that have two
     (``"bsr"`` | ``"gather"`` | ``"auto"``: by fill); Aᵀ takes A's."""
     backend = select_backend(engine)
     weighted = _weighted_graph(graph, aggregation)
@@ -155,7 +155,7 @@ def make_fused_aggregate(
         agg_attention = None
         fwd = bwd = None
         if build_attention:
-            fwd, bwd = _operand_pair(backend, weighted, br, bc, "bsr")
+            fwd, bwd = _operand_pair(backend, weighted, br, bc, fmt)
             agg_attention = backend.spmm_attention(fwd, bwd,
                                                    interpret=interpret, bf=bf)
 
@@ -168,8 +168,7 @@ def make_fused_aggregate(
         )
 
     # (A, Aᵀ) operands — the paper's CSR-forward / CSC-backward pairing
-    fwd, bwd = _operand_pair(backend, weighted, br, bc,
-                             "bsr" if build_attention else fmt)
+    fwd, bwd = _operand_pair(backend, weighted, br, bc, fmt)
     agg = backend.spmm_transposed_vjp(fwd, bwd, interpret=interpret)
     agg_epilogue = backend.spmm_fused_epilogue(fwd, bwd, interpret=interpret,
                                                bf=bf)

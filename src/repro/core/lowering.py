@@ -83,10 +83,12 @@ class AttentionPlan:
 
     The attention sibling of ``EpiloguePlan``: declares how a GAT /
     GraphTransformer layer's edge-softmax aggregation executes. ``fused``
-    means the flash-style BSR kernel (online segment softmax + aggregation
-    in one pass, per-edge scores never materialised) with the recompute VJP
-    from the saved per-row (max, denominator) stats; unfused is the segment
-    (gather) path with autodiff through the per-edge tensors.
+    means the flash-style kernels (online segment softmax + aggregation in
+    one pass, per-edge scores never materialised) over the layer's operand
+    (``operand``: BSR blocks or CSR row gather, by the fill rule), with the
+    recompute VJP from the saved per-row (max, denominator) stats; unfused
+    is the segment (gather) path with autodiff through the per-edge
+    tensors.
     """
 
     heads: int
@@ -94,19 +96,32 @@ class AttentionPlan:
     fused: bool
     vjp: str                # "recompute(m,l)" | "autodiff"
     formula: str            # human-readable algebra, for plan dumps
+    operand: str = "bsr"    # "bsr" | "gather": the fused kernels' operand
 
     def describe(self) -> str:
-        mode = "fused-bsr" if self.fused else "segment"
+        mode = f"fused-{self.operand}" if self.fused else "segment"
         return (f"{self.heads}h x {self.head_dim} {mode} vjp={self.vjp} "
                 f"{self.formula}")
 
 
-def _attention_binding(heads: int, d_out: int, fused: bool) -> AttentionPlan:
-    head_dim = max(d_out // heads, 1)
+def attention_head_dim(kind: str, heads: int, d_out: int,
+                       is_last: bool) -> int:
+    """Per-head width of an attention layer with ``d_out`` outputs. GAT
+    concatenates its heads in hidden layers (``d_out = heads · D``) and
+    averages them in the last (``D = d_out``); GT projects the concatenated
+    heads back to ``d_out``."""
+    if kind == "GAT" and is_last:
+        return d_out
+    return max(d_out // heads, 1)
+
+
+def _attention_binding(kind: str, heads: int, d_out: int, is_last: bool,
+                       fused: bool, operand: str = "bsr") -> AttentionPlan:
     return AttentionPlan(
-        heads=heads, head_dim=head_dim, fused=fused,
-        vjp="recompute(m,l)" if fused else "autodiff",
-        formula="softmax_j(leaky_relu(a_dst·z_i + a_src·z_j))·z_j")
+        heads=heads, head_dim=attention_head_dim(kind, heads, d_out, is_last),
+        fused=fused, vjp="recompute(m,l)" if fused else "autodiff",
+        formula="softmax_j(leaky_relu(a_dst·z_i + a_src·z_j))·z_j",
+        operand=operand)
 
 
 def is_attention_arch(kind: str) -> bool:
@@ -492,7 +507,9 @@ def lower_sampled(
                 sparse_path=(path == "sparse"))
         attention = None
         if is_attn:
-            attention = _attention_binding(config.gat_heads, d_out, emit_attn)
+            attention = _attention_binding(
+                kind, config.gat_heads, d_out, i == config.n_layers - 1,
+                emit_attn)
 
         layers.append(LayerPlan(
             index=i, op_kind=kind, d_in=d_in, d_out=d_out,
@@ -698,7 +715,9 @@ def lower_distributed(
                 sparse_path=(path == "sparse"))
         attention = None
         if is_attn:
-            attention = _attention_binding(config.gat_heads, d_out, emit_attn)
+            attention = _attention_binding(
+                kind, config.gat_heads, d_out, i == config.n_layers - 1,
+                emit_attn)
 
         layers.append(LayerPlan(
             index=i, op_kind=kind, d_in=d_in, d_out=d_out,
@@ -865,7 +884,8 @@ def lower(
     ``benchmarks/bench_fusion.py`` sweeps. ``fuse_attention=False`` keeps
     attention archs (GAT / GT) on the segment-softmax gather path — the
     A/B lever ``benchmarks/bench_attention.py`` sweeps; by default they
-    lower onto the fused BSR flash-attention kernel on pallas/xla.
+    lower onto the fused flash-attention kernels on pallas/xla, over the
+    operand format the fill rule picks (pallas: BSR or CSR row gather).
 
     ``layout`` selects the layout-optimization stage (DESIGN.md §9):
     ``"auto"`` reorders the graph (degree / RCM, whichever packs BSR blocks
@@ -909,7 +929,8 @@ def lower(
     n_nodes = graph_exec.n_rows
 
     # the aggregation operand's format, by the fill the layout counted
-    # (backends with one format ignore it; attention always takes BSR)
+    # (backends with one format ignore it); attention takes it as the SpMM
+    # does, with kernels of its own for each format
     fmt = (operand_format(graph_exec.nnz, lp.n_blocks) if lp.n_blocks
            else "auto")
     graph_op = make_fused_aggregate(
@@ -917,9 +938,7 @@ def lower(
         engine=backend, bf=lp.bf or None, build_attention=emit_attn, fmt=fmt)
     operand = getattr(graph_op.fwd_operand, "format", "")
     operand_note = f"{operand} operand" if operand else ""
-    if operand and emit_attn:
-        operand_note += ": attention masks are blocks"
-    elif operand and lp.n_blocks:
+    if operand and lp.n_blocks:
         operand_note += (
             f": {graph_exec.nnz / lp.n_blocks:.2f} nonzeros per "
             f"{lp.br}x{lp.bc} block, pallas gathers below {GATHER_FILL:g}")
@@ -953,6 +972,9 @@ def lower(
                    for op in (graph_op.fwd_operand, graph_op.bwd_operand)]
         for name in ("gather", "bsr"):
             count(f"operand_{name}", formats.count(name))
+            if is_attn:  # attention layers on each format's fused kernels
+                count(f"attention_{name}", config.n_layers
+                      if attn_bound and operand == name else 0)
         for i in range(config.n_layers):
             d_in, d_out = dims[i], dims[i + 1]
             if i == 0:
@@ -1004,8 +1026,9 @@ def lower(
                     sparse_path=sparse_xw is not None)
             attention = None
             if is_attn:
-                attention = _attention_binding(config.gat_heads, d_out,
-                                               attn_bound)
+                attention = _attention_binding(
+                    kind, config.gat_heads, d_out,
+                    i == config.n_layers - 1, attn_bound, operand or "bsr")
 
             if operand_note:
                 note = f"{note}; {operand_note}" if note else operand_note

@@ -514,6 +514,8 @@ _ATTENTION_ARCHS = ("GAT", "GT")
 
 
 def _check_bindings(v: _Ctx, plan, allowed_prefixes: tuple[str, ...]) -> None:
+    from repro.core.lowering import attention_head_dim  # lowering imports us
+
     layers = plan.layers
     for i, layer in enumerate(layers):
         if i + 1 < len(layers) and layer.d_out != layers[i + 1].d_in:
@@ -533,7 +535,9 @@ def _check_bindings(v: _Ctx, plan, allowed_prefixes: tuple[str, ...]) -> None:
                    f"{layer.op_kind}")
         if layer.attention is not None and is_attn:
             a = layer.attention
-            if a.heads < 1 or a.head_dim != max(layer.d_out // a.heads, 1):
+            want = attention_head_dim(layer.op_kind, max(a.heads, 1),
+                                      layer.d_out, i == len(layers) - 1)
+            if a.heads < 1 or a.head_dim != want:
                 v.flag(i, "attention", "binding.attention_arch",
                        f"attention geometry {a.heads}h x {a.head_dim} "
                        f"inconsistent with d_out={layer.d_out}")

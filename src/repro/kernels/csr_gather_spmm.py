@@ -61,6 +61,113 @@ UNROLL = 16
 LANES = 128
 
 
+def gather_loop(*, t, tile_ptr, k: int, streams, x, gbuf, idx_sem, row_sem,
+                body, init=None, unroll: int = UNROLL):
+    """Drive destination tile ``t``'s nonzeros through the double-buffered
+    gather: the machinery every row-gather kernel shares.
+
+    ``streams`` pairs each 1-D HBM nonzero stream with its SMEM double
+    buffer ``[2·k]``; the first pair must be the column indices (the rows of
+    ``x [n, 1, F]`` to gather). ``init()`` runs once the tile's bounds are
+    known; ``body(j, row)`` then runs per nonzero once its row has landed,
+    with ``j`` its position in the SMEM buffers and ``row()`` reading the
+    landed row ``[1, F]`` from the gather buffer. The issue and body loops
+    run ``unroll`` nonzeros per iteration.
+    """
+    s, e = tile_ptr[t], tile_ptr[t + 1]
+    q0 = s // k
+    nch = jnp.where(e > s, (e + k - 1) // k - q0, 0)
+    if init is not None:
+        init()
+    cols_s = streams[0][1]
+
+    def bounds(c):
+        """(window start, first, end) of chunk ``c``'s nonzeros."""
+        q = q0 + c
+        return q * k, jnp.maximum(s, q * k), jnp.minimum(e, q * k + k)
+
+    def idx_copies(c, slot):
+        src = pl.ds(pl.multiple_of((q0 + c) * k, k), k)
+        dst = pl.ds(slot * k, k)
+        return [pltpu.make_async_copy(a.at[src], b.at[dst], idx_sem.at[slot])
+                for a, b in streams]
+
+    def each(lo, hi, fn):
+        """``fn(p)`` for p in [lo, hi), ``unroll`` per iteration."""
+        n_groups = (hi - lo) // unroll
+
+        def group(g, carry):
+            for u in range(unroll):
+                fn(lo + g * unroll + u)
+            return carry
+
+        def tail(p, carry):
+            fn(p)
+            return carry
+
+        jax.lax.fori_loop(0, n_groups, group, 0)
+        jax.lax.fori_loop(lo + n_groups * unroll, hi, tail, 0)
+
+    def issue_rows(c, slot):
+        base, lo, hi = bounds(c)
+
+        def one(p):
+            col = cols_s[slot * k + p - base]
+            pltpu.make_async_copy(x.at[col], gbuf.at[slot, p - base],
+                                  row_sem.at[slot]).start()
+
+        each(lo, hi, one)
+
+    def wait_rows(c, slot):
+        _, lo, hi = bounds(c)
+        m = hi - lo
+        for b in reversed(range(k.bit_length())):
+            @pl.when(((m >> b) & 1) == 1)
+            def _():
+                buf = gbuf.at[slot, pl.ds(0, 1 << b)]
+                pltpu.make_async_copy(buf, buf, row_sem.at[slot]).wait()
+
+    def accumulate(c, slot):
+        base, lo, hi = bounds(c)
+        each(lo, hi, lambda p: body(slot * k + p - base,
+                                    lambda: gbuf[slot, p - base]))
+
+    @pl.when(nch > 0)
+    def _():
+        for cp in idx_copies(0, 0):
+            cp.start()
+
+        @pl.when(nch > 1)
+        def _():
+            for cp in idx_copies(1, 1):
+                cp.start()
+
+        for cp in idx_copies(0, 0):
+            cp.wait()
+        issue_rows(0, 0)
+
+        def step(c, carry):
+            slot = c % 2
+
+            @pl.when(c + 1 < nch)
+            def _():
+                for cp in idx_copies(c + 1, 1 - slot):
+                    cp.wait()
+                issue_rows(c + 1, 1 - slot)
+
+            wait_rows(c, slot)
+            accumulate(c, slot)
+
+            @pl.when(c + 2 < nch)
+            def _():
+                for cp in idx_copies(c + 2, slot):
+                    cp.start()
+
+            return carry
+
+        jax.lax.fori_loop(0, nch, step, 0)
+
+
 def _make_kernel(*, tm: int, k: int, has_self: bool, has_bias: bool,
                  relu: bool):
     """Kernel specialised to its (static) epilogue spec.
@@ -83,103 +190,19 @@ def _make_kernel(*, tm: int, k: int, has_self: bool, has_bias: bool,
             next(it) for _ in range(6))
 
         t = pl.program_id(0)
-        s, e = tile_ptr[t], tile_ptr[t + 1]
-        q0 = s // k
-        nch = jnp.where(e > s, (e + k - 1) // k - q0, 0)
-        y_ref[...] = jnp.zeros(y_ref.shape, jnp.float32)
 
-        def bounds(c):
-            """(window start, first, end) of chunk ``c``'s nonzeros."""
-            q = q0 + c
-            return q * k, jnp.maximum(s, q * k), jnp.minimum(e, q * k + k)
+        def init():
+            y_ref[...] = jnp.zeros(y_ref.shape, jnp.float32)
 
-        def idx_copies(c, slot):
-            src = pl.ds(pl.multiple_of((q0 + c) * k, k), k)
-            dst = pl.ds(slot * k, k)
-            return [pltpu.make_async_copy(a.at[src], b.at[dst],
-                                          idx_sem.at[slot])
-                    for a, b in ((indices, cols_s), (rows, rows_s),
-                                 (values, vals_s))]
+        def one(j, row):
+            r = rows_s[j] - t * tm
+            y_ref[pl.ds(r, 1), :] += vals_s[j] * row()
 
-        def each(lo, hi, body):
-            """``body(p)`` for p in [lo, hi), ``UNROLL`` per iteration."""
-            n_groups = (hi - lo) // UNROLL
-
-            def group(g, carry):
-                for u in range(UNROLL):
-                    body(lo + g * UNROLL + u)
-                return carry
-
-            def tail(p, carry):
-                body(p)
-                return carry
-
-            jax.lax.fori_loop(0, n_groups, group, 0)
-            jax.lax.fori_loop(lo + n_groups * UNROLL, hi, tail, 0)
-
-        def issue_rows(c, slot):
-            base, lo, hi = bounds(c)
-
-            def one(p):
-                col = cols_s[slot * k + p - base]
-                pltpu.make_async_copy(x.at[col], gbuf.at[slot, p - base],
-                                      row_sem.at[slot]).start()
-
-            each(lo, hi, one)
-
-        def wait_rows(c, slot):
-            _, lo, hi = bounds(c)
-            m = hi - lo
-            for b in reversed(range(k.bit_length())):
-                @pl.when(((m >> b) & 1) == 1)
-                def _():
-                    buf = gbuf.at[slot, pl.ds(0, 1 << b)]
-                    pltpu.make_async_copy(buf, buf, row_sem.at[slot]).wait()
-
-        def accumulate(c, slot):
-            base, lo, hi = bounds(c)
-
-            def one(p):
-                j = slot * k + p - base
-                r = rows_s[j] - t * tm
-                y_ref[pl.ds(r, 1), :] += vals_s[j] * gbuf[slot, p - base]
-
-            each(lo, hi, one)
-
-        @pl.when(nch > 0)
-        def _():
-            for cp in idx_copies(0, 0):
-                cp.start()
-
-            @pl.when(nch > 1)
-            def _():
-                for cp in idx_copies(1, 1):
-                    cp.start()
-
-            for cp in idx_copies(0, 0):
-                cp.wait()
-            issue_rows(0, 0)
-
-            def step(c, carry):
-                slot = c % 2
-
-                @pl.when(c + 1 < nch)
-                def _():
-                    for cp in idx_copies(c + 1, 1 - slot):
-                        cp.wait()
-                    issue_rows(c + 1, 1 - slot)
-
-                wait_rows(c, slot)
-                accumulate(c, slot)
-
-                @pl.when(c + 2 < nch)
-                def _():
-                    for cp in idx_copies(c + 2, slot):
-                        cp.start()
-
-                return carry
-
-            jax.lax.fori_loop(0, nch, step, 0)
+        gather_loop(t=t, tile_ptr=tile_ptr, k=k,
+                    streams=((indices, cols_s), (rows, rows_s),
+                             (values, vals_s)),
+                    x=x, gbuf=gbuf, idx_sem=idx_sem, row_sem=row_sem,
+                    body=one, init=init)
 
         if has_self or has_bias or relu:
             acc = y_ref[...]

@@ -28,6 +28,11 @@ from repro.kernels.bsr_attention import (
     bsr_attention_bwd_row,
     bsr_attention_fwd,
 )
+from repro.kernels.csr_gather_attention import (
+    csr_gather_attention_bwd_col,
+    csr_gather_attention_bwd_row,
+    csr_gather_attention_fwd,
+)
 from repro.kernels.csr_gather_spmm import (
     csr_gather_spmm,
     csr_gather_spmm_fused_epilogue,
@@ -704,6 +709,100 @@ def _mha_bwd(geom, bf, interpret, inner, res, dy):
 
 
 sparse_mha_pair.defvjp(_mha_fwd, _mha_bwd)
+
+
+# ---------------------------------------------------------------------------
+# Fused sparse multi-head attention over CSR operands: the row-gather
+# kernels, with the same recompute VJP from the saved (m, l) statistics.
+# ---------------------------------------------------------------------------
+
+_FULL = jax.lax.Precision.HIGHEST
+
+
+def _csr_attn_fwd(fwd_arrays, z, a_src, a_dst, geom, interpret):
+    """(out [n_dst,H,Dh], m, l [n_dst,H], asrc, adst) of the gather kernel."""
+    n_dst, _ = geom
+    n, h, dh = z.shape
+    z32 = z.astype(jnp.float32)
+    # the scores' projections in full float32: softmax logits are where a
+    # one-pass bf16 product would show (O(N·H·Dh) work)
+    asrc = jnp.einsum("nhd,hd->nh", z32, a_src.astype(jnp.float32),
+                      precision=_FULL)
+    adst = jnp.einsum("nhd,hd->nh", z32[:n_dst], a_dst.astype(jnp.float32),
+                      precision=_FULL)
+    indptr, indices, rows, _ = fwd_arrays
+    out, m, l = csr_gather_attention_fwd(
+        indptr, indices, rows, z32.reshape(n, h * dh), asrc, adst, heads=h,
+        n_rows=n_dst, interpret=interpret)
+    return out.reshape(n_dst, h, dh), m, l, asrc, adst
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def csr_mha_pair(fwd_arrays, bwd_arrays, z, a_src, a_dst, geom,
+                 interpret=None):
+    """Fused sparse multi-head attention over a CSR pair.
+
+    The row-gather sibling of ``sparse_mha_pair``: ``fwd_arrays`` and
+    ``bwd_arrays`` are the (indptr, indices, rows, values) of A and Aᵀ (the
+    values are ignored: the nonzero pattern is the mask), ``geom`` is A's
+    ``(n_rows, n_cols)``. Differentiable in ``z [n_src, H, Dh]``, ``a_src``
+    and ``a_dst [H, Dh]``; returns ``[n_dst, H, Dh]``. The VJP recomputes the
+    weights from the saved ``(m, l)`` (O(N·H) residuals): a row pass over A
+    for the destination-side score gradient, a column pass over Aᵀ for the
+    value path and the source-side score gradient.
+    """
+    interpret = default_interpret() if interpret is None else interpret
+    return _csr_attn_fwd(fwd_arrays, z, a_src, a_dst, geom, interpret)[0]
+
+
+def _csr_mha_fwd(fwd_arrays, bwd_arrays, z, a_src, a_dst, geom, interpret):
+    interpret = default_interpret() if interpret is None else interpret
+    out, m, l, asrc, adst = _csr_attn_fwd(fwd_arrays, z, a_src, a_dst, geom,
+                                          interpret)
+    return out, (fwd_arrays, bwd_arrays, z, a_src, a_dst, out, m, l, asrc,
+                 adst)
+
+
+def _csr_mha_bwd(geom, interpret, res, dy):
+    fwd_arrays, bwd_arrays, z, a_src, a_dst, out, m, l, asrc, adst = res
+    interpret = default_interpret() if interpret is None else interpret
+    n_dst, n_src = geom
+    h, dh = z.shape[1], z.shape[2]
+    z32 = z.astype(jnp.float32)
+    dy = dy.astype(jnp.float32)
+    r = jnp.einsum("nhd,nhd->nh", dy, out, precision=_FULL)
+    z2, dy2 = z32.reshape(n_src, h * dh), dy.reshape(n_dst, h * dh)
+    kw = dict(heads=h, interpret=interpret)
+    dc = csr_gather_attention_bwd_row(
+        *fwd_arrays[:3], z2, asrc, adst, dy2, r, m, l, n_rows=n_dst, **kw)
+    dzv, dd = csr_gather_attention_bwd_col(
+        *bwd_arrays[:3], asrc, adst, z2, dy2, r, m, l, n_rows=n_src, **kw)
+    a_src32 = a_src.astype(jnp.float32)
+    a_dst32 = a_dst.astype(jnp.float32)
+    dz = (dzv.reshape(n_src, h, dh) + dd[..., None] * a_src32[None]
+          + _fit_rows(dc, n_src)[..., None] * a_dst32[None])
+    da_src = jnp.einsum("nh,nhd->hd", dd, z32, precision=_FULL)
+    da_dst = jnp.einsum("nh,nhd->hd", dc, z32[:n_dst], precision=_FULL)
+    return (_zero_cotangents(fwd_arrays), _zero_cotangents(bwd_arrays),
+            dz.astype(z.dtype), da_src.astype(a_src.dtype),
+            da_dst.astype(a_dst.dtype))
+
+
+csr_mha_pair.defvjp(_csr_mha_fwd, _csr_mha_bwd)
+
+
+def build_gather_mha(fwd: "CSRDevice", bwd: "CSRDevice",
+                     interpret: bool | None = None):
+    """Differentiable fused-attention closure over a (A, Aᵀ) CSRDevice pair,
+    with ``build_sparse_mha``'s calling convention: ``mha(z, a_src, a_dst)``
+    on ``z [n_src, H, Dh]`` -> ``[n_dst, H, Dh]``."""
+    geom = (fwd.n_rows, fwd.n_cols)
+
+    def mha(z, a_src, a_dst):
+        return csr_mha_pair(fwd.arrays, bwd.arrays, z, a_src, a_dst, geom,
+                            interpret)
+
+    return mha
 
 
 def derive_last_in_row(block_rows: jax.Array) -> jax.Array:

@@ -1,5 +1,11 @@
 """GNN model zoo — GCN, GraphSAGE, GIN, GAT (paper §III-A).
 
+GAT is DGL's ogbn-arxiv ``GATConv`` stack: per head ``k``,
+``O^k = Σ_j softmax_j(leaky_relu(a_dst^k·Z_i^k + a_src^k·Z_j^k)) Z_j^k
++ (H·W_res)^k`` with ``Z = H·W``; hidden layers concatenate the heads
+(``d_out = heads · D``), add a bias and apply the activation; the last
+averages the heads and adds its bias.
+
 Functional style: ``init(key) -> params`` and ``apply(params, x) -> logits``.
 A model executes a ``ModelPlan`` produced by the lowering pass
 (``core/lowering.py``): each layer's feature transform and aggregation run
@@ -9,8 +15,9 @@ without a plan lowers one on the spot (dense paths everywhere, since the
 feature matrix is unknown at that point).
 
 Attention archs (GAT, and the GT graph-transformer layer) lower onto the
-fused BSR flash-attention primitive ``spmm_attention`` by default on
-pallas/xla — per-edge scores and weights never materialise in HBM — and
+fused flash-attention primitive ``spmm_attention`` by default on
+pallas/xla (BSR or CSR row-gather kernels, by the fill rule) — per-edge
+scores and weights never materialise in HBM — and
 fall back to the ``segment_softmax_aggregate`` gather path when the plan
 was lowered with ``fuse_attention=False`` or on the gather backend.
 
@@ -78,7 +85,24 @@ def init_params(config: GNNConfig, key) -> dict:
                 "w2": xavier_init(k1, (d_out, d_out)),
                 "b2": jnp.zeros((d_out,)),
             }
-        elif config.kind in ("GAT", "GT"):
+        elif config.kind == "GAT":
+            # DGL's GATConv (ogbn-arxiv example): heads concatenated in
+            # hidden layers (d_out = heads · D), averaged in the last (D =
+            # d_out); a bias-free residual projection to heads · D
+            h = config.gat_heads
+            is_last = i == config.n_layers - 1
+            if not is_last and d_out % h:
+                raise ValueError(f"GAT hidden width {d_out} is not a "
+                                 f"multiple of {h} heads")
+            dh = d_out if is_last else d_out // h
+            layer = {
+                "w": xavier_init(k0, (d_in, h * dh)),
+                "a_src": xavier_init(k1, (h, dh)),
+                "a_dst": xavier_init(k2, (h, dh)),
+                "w_res": xavier_init(k3, (d_in, h * dh)),
+                "b": jnp.zeros((dh if is_last else h * dh,)),
+            }
+        elif config.kind == "GT":
             h = config.gat_heads
             dh = max(d_out // h, 1)
             layer = {
@@ -87,11 +111,10 @@ def init_params(config: GNNConfig, key) -> dict:
                 "a_dst": xavier_init(k2, (h, dh)),
                 "b": jnp.zeros((d_out,)),
                 "proj": xavier_init(k3, (h * dh, d_out)),
-            }
-            if config.kind == "GT":
                 # graph-transformer residual branch (pre-attention input)
-                k4 = jax.random.fold_in(k3, 1)
-                layer["w_res"] = xavier_init(k4, (d_in, d_out))
+                "w_res": xavier_init(jax.random.fold_in(k3, 1),
+                                     (d_in, d_out)),
+            }
         else:
             raise ValueError(config.kind)
         params["layers"].append(layer)
@@ -205,15 +228,23 @@ def apply_layer(config: GNNConfig, layer: dict, x: jax.Array, ops: LayerOps,
                 z = (1.0 + layer["eps"]) * res(x) + ops.aggregate(x)
             z1 = z @ layer["w1"] + layer["b1"]
             y = config.activation(z1) @ layer["w2"] + layer["b2"]
-    elif kind in ("GAT", "GT"):
+    elif kind == "GAT":
+        # O^k = Σ_j α_ij^k Z_j^k + (H·W_res)^k per head; hidden layers
+        # concatenate the heads, the last averages them
+        out = ops.gat_attention(mm(layer["w"]), layer["a_src"],
+                                layer["a_dst"], config.gat_heads)
+        n, h, dh = out.shape
+        out = out + res(mm(layer["w_res"])).reshape(n, h, dh)
+        y = (out.mean(axis=1) if is_last else out.reshape(n, h * dh)) \
+            + layer["b"]
+    elif kind == "GT":
         z = mm(layer["w"])  # [N, heads*dh]
         out = ops.gat_attention(z, layer["a_src"], layer["a_dst"],
                                 config.gat_heads)  # [N, heads, dh]
         y = out.reshape(out.shape[0], -1) @ layer["proj"] + layer["b"]
-        if kind == "GT":
-            # transformer-style residual around the attention block; the
-            # restrict maps the (possibly wider) src frontier onto dst rows
-            y = y + res(x) @ layer["w_res"]
+        # transformer-style residual around the attention block; the
+        # restrict maps the (possibly wider) src frontier onto dst rows
+        y = y + res(x) @ layer["w_res"]
     else:
         raise ValueError(kind)
     return y if is_last else config.activation(y)
@@ -248,7 +279,7 @@ class GNNModel:
         # legacy flag the seed set when monkey-patching the input path
         self.sparse_input_bound = any(
             l.feature_path == "sparse" for l in plan.layers)
-        # fused BSR flash-attention: bound iff the plan's aggregation
+        # fused flash-attention (BSR or CSR): bound iff the plan's aggregation
         # primitive is spmm_attention AND the graph op carries the operator
         self._fuse_attention = (
             use_fused and self.op.aggregate_attention is not None
@@ -268,7 +299,7 @@ class GNNModel:
         return self.op.baseline(x)
 
     def _gat_attention(self, z: jax.Array, a_src, a_dst, heads: int) -> jax.Array:
-        """Edge-softmax attention: the fused BSR flash-attention operator
+        """Edge-softmax attention: the fused flash-attention operator
         when the plan bound one, else the backend's segment primitive."""
         if self._fuse_attention:
             return self.op.aggregate_attention(z, a_src, a_dst, heads)

@@ -184,7 +184,7 @@ def test_fill_rule_picks_operand_format(rng, case):
     graph = _banded() if case == "banded" else _power_law(rng)
     plan = _lower(graph, kind="GAT" if case == "attention" else "GCN")
     gop = plan.graph_op
-    want = "gather" if case == "power-law" else "bsr"
+    want = "bsr" if case == "banded" else "gather"
     assert {layer.operand for layer in plan.layers} == {want}
     assert gop.fwd_operand.format == gop.bwd_operand.format == want
     assert all(f"{want} operand" in layer.note for layer in plan.layers)

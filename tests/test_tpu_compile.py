@@ -27,6 +27,11 @@ from repro.kernels.bsr_spmm import (
     bsr_spmm_fused_epilogue,
     bsr_spmm_masked,
 )
+from repro.kernels.csr_gather_attention import (
+    csr_gather_attention_bwd_col,
+    csr_gather_attention_bwd_row,
+    csr_gather_attention_fwd,
+)
 from repro.kernels.csr_gather_spmm import (
     csr_gather_spmm,
     csr_gather_spmm_fused_epilogue,
@@ -144,6 +149,40 @@ def test_csr_gather_compiles(shape, name, f):
     _compile(*_gather(shape, name, N_NODES, NNZ, f))
 
 
+GATHER_ATTENTION = {
+    "csr_gather_attention_fwd": lambda shape, n, w, k: (
+        lambda *a: csr_gather_attention_fwd(*a[:3], *a[4:], heads=k,
+                                            n_rows=n),
+        shape((n, w)), shape((n, k)), shape((n, k))),
+    "csr_gather_attention_bwd_row": lambda shape, n, w, k: (
+        lambda *a: csr_gather_attention_bwd_row(*a[:3], *a[4:], heads=k,
+                                                n_rows=n),
+        shape((n, w)), shape((n, k)), shape((n, k)), shape((n, w)),
+        *[shape((n, k))] * 3),
+    "csr_gather_attention_bwd_col": lambda shape, n, w, k: (
+        lambda *a: csr_gather_attention_bwd_col(*a[:3], *a[4:], heads=k,
+                                                n_rows=n),
+        shape((n, k)), shape((n, k)), shape((n, w)), shape((n, w)),
+        *[shape((n, k))] * 3),
+}
+
+
+def _gather_attention(shape, name, n, nnz, w, heads=3):
+    """``(fn, *args)`` of one gather-attention kernel over a CSR of ``n``
+    rows (its values ride along unused)."""
+    fn, *rest = GATHER_ATTENTION[name](shape, n, w, heads)
+    return (fn, *_csr(shape, n, nnz), *rest)
+
+
+@pytest.mark.parametrize("w", [750, 120])
+@pytest.mark.parametrize("name", list(GATHER_ATTENTION))
+def test_csr_gather_attention_compiles(shape, name, w):
+    """The gather-attention kernels at arxiv's size and the published GAT's
+    widths (3 heads of 250, concatenated; 3 heads of 40 in the last
+    layer): two chunks of packed rows in VMEM, up to 768 lanes each."""
+    _compile(*_gather_attention(shape, name, N_NODES, NNZ, w))
+
+
 HEADS = [pytest.param(4, 64, id="4x64"), pytest.param(8, 8, id="8x8")]
 
 
@@ -221,6 +260,10 @@ KERNELS.update({
     name: lambda shape, stream, n, _name=name: _gather(shape, _name, n, 4096,
                                                        128)
     for name in GATHER})
+KERNELS.update({
+    name: lambda shape, stream, n, _name=name: _gather_attention(
+        shape, _name, n, 4096, 120)
+    for name in GATHER_ATTENTION})
 
 
 @pytest.mark.parametrize("name", list(KERNELS))
