@@ -4,10 +4,11 @@ Two operand formats, chosen per graph by fill (``core/layout.py``
 ``operand_format``): where nonzeros cluster into blocks, CSR -> BSR once
 (the MXU consumes dense (BR, BC) tiles, the DMA engine moves whole blocks)
 and every ``spmm`` runs ``kernels/bsr_spmm.py``; where they do not, the
-operand stays CSR and every ``spmm`` gathers one source row per nonzero
-(``kernels/csr_gather_spmm.py``); attention follows the same rule, with
-its own kernels for each format (``kernels/bsr_attention.py``,
-``kernels/csr_gather_attention.py``). Off-TPU
+operand stays CSR and every ``spmm`` sums one source row per nonzero,
+read from a VMEM window of the source (``kernels/csr_gather_spmm.py``);
+attention follows the same rule, with its own kernels for each format
+(``kernels/bsr_attention.py``, ``kernels/csr_gather_attention.py``), over
+a row-ordered CSR (``build_attention_operand``). Off-TPU
 the kernels run in Pallas interpret mode — numerically exact but slow,
 which is why ``priority()`` drops off-TPU and auto-selection prefers the
 XLA backend there.
@@ -36,13 +37,23 @@ class PallasBackend(Backend):
 
     def build_spmm_operand(self, csr: CSRGraph, br: int = 8,
                            bc: Optional[int] = None, fmt: str = "bsr"):
+        # the SpMM reads CSR by tile, sorted by column within each tile
+        return self._operand(csr, br, bc, fmt, column_tile=kops.WINDOW_TILE)
+
+    def build_attention_operand(self, csr: CSRGraph, br: int = 8,
+                                bc: Optional[int] = None, fmt: str = "bsr"):
+        # the gather-attention kernels read CSR row by row
+        return self._operand(csr, br, bc, fmt, column_tile=None)
+
+    @staticmethod
+    def _operand(csr, br, bc, fmt, column_tile):
         if fmt == "auto":
             from repro.core.layout import operand_format
 
             bc_eff = adaptive_bc(csr.n_cols) if bc is None else bc
             fmt = operand_format(csr.nnz, bsr_block_count(csr, br, bc_eff))
         if fmt == "gather":
-            return kops.CSRDevice.from_csr(csr)
+            return kops.CSRDevice.from_csr(csr, column_tile=column_tile)
         return kops.BSRDevice.from_bsr(csr_to_bsr(csr, br=br, bc=bc))
 
     def operand_bytes(self, operand) -> int:

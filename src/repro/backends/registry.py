@@ -206,6 +206,13 @@ class Backend:
         ignore it."""
         raise NotImplementedError
 
+    def build_attention_operand(self, csr: CSRGraph, br: int = 8,
+                                bc: Optional[int] = None, fmt: str = "bsr"):
+        """The operand for ``spmm_attention`` (and the SpMMs bound beside
+        it), with ``build_spmm_operand``'s arguments; the same operand
+        unless a backend's attention kernels read another layout."""
+        return self.build_spmm_operand(csr, br=br, bc=bc, fmt=fmt)
+
     def operand_bytes(self, operand) -> int:
         raise NotImplementedError
 

@@ -106,11 +106,14 @@ class FusedGraphOp:
 
 
 def _operand_pair(backend: Backend, weighted: CSRGraph, br: int,
-                  bc: int | None, fmt: str):
-    """(A, Aᵀ) on ``backend``, both in the format A takes."""
-    fwd = backend.build_spmm_operand(weighted, br=br, bc=bc, fmt=fmt)
-    bwd = backend.build_spmm_operand(weighted.transpose(), br=br, bc=bc,
-                                     fmt=getattr(fwd, "format", "bsr"))
+                  bc: int | None, fmt: str, attention: bool):
+    """(A, Aᵀ) on ``backend``, both in the format A takes; ``attention``
+    builds them for the fused attention kernels."""
+    build = (backend.build_attention_operand if attention
+             else backend.build_spmm_operand)
+    fwd = build(weighted, br=br, bc=bc, fmt=fmt)
+    bwd = build(weighted.transpose(), br=br, bc=bc,
+                fmt=getattr(fwd, "format", "bsr"))
     return fwd, bwd
 
 
@@ -155,7 +158,8 @@ def make_fused_aggregate(
         agg_attention = None
         fwd = bwd = None
         if build_attention:
-            fwd, bwd = _operand_pair(backend, weighted, br, bc, fmt)
+            fwd, bwd = _operand_pair(backend, weighted, br, bc, fmt,
+                                     attention=True)
             agg_attention = backend.spmm_attention(fwd, bwd,
                                                    interpret=interpret, bf=bf)
 
@@ -168,7 +172,8 @@ def make_fused_aggregate(
         )
 
     # (A, Aᵀ) operands — the paper's CSR-forward / CSC-backward pairing
-    fwd, bwd = _operand_pair(backend, weighted, br, bc, fmt)
+    fwd, bwd = _operand_pair(backend, weighted, br, bc, fmt,
+                             attention=build_attention)
     agg = backend.spmm_transposed_vjp(fwd, bwd, interpret=interpret)
     agg_epilogue = backend.spmm_fused_epilogue(fwd, bwd, interpret=interpret,
                                                bf=bf)

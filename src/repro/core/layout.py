@@ -62,7 +62,10 @@ BLOCK_OVERHEAD = 4096.0
 #: gather ~25 ns per nonzero at 40 to 256 lanes (PERF.md §5); 0.3 µs /
 #: 25 ns = 12 at one lane tile. Wider rows only move the crossover up (BSR
 #: pays per lane tile, the gather per row), so one tile is the
-#: conservative ratio.
+#: conservative ratio. The SpMM now reads a row from a VMEM window for
+#: ~6.8 ns instead (``kernels/csr_gather_spmm.py``, PERF.md §6), which
+#: would put its crossover near 0.3 µs / 6.8 ns ≈ 44; no graph here runs the
+#: BSR side to calibrate that (PERF.md §7), and attention still pays a copy.
 GATHER_FILL = 12.0
 
 #: timed candidates since import — the cache-determinism proof observable

@@ -63,8 +63,12 @@ INVARIANT_CATALOG = {
     "csr.indptr": "indptr int32 of n_rows + 1 entries, non-decreasing "
                   "from 0 to nnz",
     "csr.indices_in_range": "column indices int32 within [0, n_cols)",
-    "csr.indices_sorted": "column indices non-decreasing within each row",
-    "csr.row_ids": "the row id stored for each nonzero agrees with indptr",
+    "csr.indices_sorted": "column indices non-decreasing within each row "
+                          "(row order) or each destination tile (tile-"
+                          "column order)",
+    "csr.row_ids": "row ids agree with indptr: in row order its expansion; "
+                   "in tile-column order inside their tile, per-row counts "
+                   "equal to indptr's, ascending within a column",
     "csr.finite": "values are finite (no NaN/Inf in operands)",
     # PR-5 permutation contract
     "perm.bijection": "perm and inv_perm are permutations of [0, n)",
@@ -454,7 +458,9 @@ def _check_csr_device(v: _Ctx, operand: str, dev, graph, aggregation,
     """Checks for a ``kernels.ops.CSRDevice`` (the row-gather operand). It
     is O(nnz) and the kernel trusts every word of it, so fast mode reads
     it whole: pointers, column range and order, finite values, and — given
-    the exec graph — per-row mass against the weighted graph."""
+    the exec graph — per-row mass against the weighted graph. The order is
+    the one the operand declares: rows (``column_tile`` None) or columns
+    within each destination tile of ``column_tile`` rows."""
     h = dev.host_view()
     indptr, indices, values = h["indptr"], h["indices"], h["values"]
     rows = h["rows"]
@@ -482,23 +488,40 @@ def _check_csr_device(v: _Ctx, operand: str, dev, graph, aggregation,
                f"[{int(p[0])}, {int(p[-1])}] for {n_rows} rows and "
                f"{nnz} nonzeros{where}")
         return  # rows are undefined: order and mass cannot be read
-    if rows.dtype != np.int32 or not np.array_equal(
-            rows, np.repeat(np.arange(n_rows), np.diff(p))):
+    tile = dev.column_tile or 1  # row order: every row its own group
+    starts = p[np.minimum(np.arange(-(-n_rows // tile) + 1) * tile, n_rows)]
+    group = np.repeat(np.arange(starts.shape[0] - 1), np.diff(starts))
+    rows_ok = (rows.dtype == np.int32 and rows.shape[0] == nnz
+               and (nnz == 0 or 0 <= int(rows.min()) <= int(rows.max())
+                    < n_rows))
+    if rows_ok and dev.column_tile:
+        same_col = indices[1:] == indices[:-1]
+        rows_ok = (np.array_equal(rows // tile, group)
+                   and np.array_equal(np.bincount(rows, minlength=n_rows),
+                                      np.diff(p))
+                   and not (same_col & (rows[1:] < rows[:-1])
+                            & (group[1:] == group[:-1])).any())
+    elif rows_ok:
+        rows_ok = np.array_equal(rows, group)
+    if not rows_ok:
         v.flag(-1, operand, "csr.row_ids",
                f"row ids {rows.dtype}[{rows.shape[0]}] disagree with indptr")
-    row_start = np.zeros(nnz, dtype=bool)
-    row_start[p[:-1][p[:-1] < nnz]] = True
-    down = np.flatnonzero((indices[1:] < indices[:-1]) & ~row_start[1:]) + 1
+    group_start = np.zeros(nnz, dtype=bool)
+    group_start[starts[:-1][starts[:-1] < nnz]] = True
+    down = np.flatnonzero((indices[1:] < indices[:-1])
+                          & ~group_start[1:]) + 1
     if down.size:
-        row = int(np.searchsorted(p, down[0], side="right")) - 1
+        where = (f"tile {int(group[down[0]])}" if dev.column_tile
+                 else f"row {int(group[down[0]])}")
         v.flag(-1, operand, "csr.indices_sorted",
                f"column {int(indices[down[0]])} after "
-               f"{int(indices[down[0] - 1])} in row {row}")
+               f"{int(indices[down[0] - 1])} in {where}")
     want = (None if graph is None
             else _weighted_row_sums(graph, aggregation, transposed))
-    if want is None or want.shape[0] != n_rows:
+    if want is None or want.shape[0] != n_rows or not rows_ok:
         return
-    got = _row_sums(p, values)
+    got = np.bincount(rows, weights=values.astype(np.float64),
+                      minlength=n_rows)
     if not np.allclose(got, want, rtol=1e-4, atol=1e-5):
         bad = int(np.argmax(np.abs(got - want)))
         v.flag(-1, operand, "layout.operand_rows",

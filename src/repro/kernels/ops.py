@@ -15,8 +15,9 @@ from typing import ClassVar
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.common.spans import span
+from repro.common.spans import count, span
 from repro.graph.csr import BSRMatrix, CSRGraph, csr_from_dense, csr_to_bsr
 from repro.kernels.bsr_spmm import (
     bsr_spmm,
@@ -34,15 +35,25 @@ from repro.kernels.csr_gather_attention import (
     csr_gather_attention_fwd,
 )
 from repro.kernels.csr_gather_spmm import (
+    WINDOW_TILE,
     csr_gather_spmm,
     csr_gather_spmm_fused_epilogue,
     csr_gather_spmm_masked,
+    window_rows,
 )
 from repro.kernels.fused_adam import fused_adam  # re-export
 
 
 def default_interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def chip_vmem() -> int | None:
+    """VMEM bytes of the TPU this process runs on, as the chip reports
+    them; ``None`` off the chip."""
+    if jax.default_backend() != "tpu":
+        return None
+    return pltpu.get_tpu_info().vmem_capacity_bytes
 
 
 def feature_tile(f: int) -> tuple[int, int]:
@@ -147,29 +158,48 @@ class BSRDevice:
 
 @dataclasses.dataclass
 class CSRDevice:
-    """Device-resident CSR: the row-gather SpMM's operand
-    (``kernels/csr_gather_spmm.py``), for graphs whose nonzeros do not fill
-    BSR blocks. O(nnz) bytes: no blocks, no padding."""
+    """Device-resident CSR: the row-gather kernels' operand
+    (``kernels/csr_gather_spmm.py``, ``kernels/csr_gather_attention.py``),
+    for graphs whose nonzeros do not fill BSR blocks. O(nnz) bytes: no
+    blocks, no padding.
+
+    ``column_tile=None`` (the default) keeps plain row order, in which each
+    row's nonzeros are contiguous and sorted by column: the attention
+    kernels' order (their online softmax runs row by row). Given a tile,
+    the nonzeros are grouped by destination tile of ``column_tile`` rows
+    and sorted by column inside each tile (stable, so rows ascend within a
+    column): the SpMM's order, in which every (tile, source window) pair
+    owns one contiguous run. ``indptr`` holds the per-row counts either
+    way, so ``indptr`` at a multiple of the tile bounds a tile's run."""
 
     indptr: jax.Array   # [n_rows + 1] int32
-    indices: jax.Array  # [nnz] int32, sorted within each row
+    indices: jax.Array  # [nnz] int32, the column of each nonzero
     rows: jax.Array     # [nnz] int32, the row of each nonzero
     values: jax.Array   # [nnz] float32
     n_rows: int
     n_cols: int
+    column_tile: int | None = None
 
     format: ClassVar[str] = "gather"
 
     @classmethod
     @span("csr_build")
-    def from_csr(cls, csr: CSRGraph) -> "CSRDevice":
+    def from_csr(cls, csr: CSRGraph,
+                 column_tile: int | None = None) -> "CSRDevice":
         rows = np.repeat(np.arange(csr.n_rows, dtype=np.int32),
                          np.diff(csr.indptr))
+        indices, values = csr.indices, csr.data
+        if column_tile:
+            order = np.argsort(
+                (rows // column_tile).astype(np.int64) * max(csr.n_cols, 1)
+                + indices, kind="stable")
+            rows, indices, values = rows[order], indices[order], values[order]
         return cls(indptr=jnp.asarray(csr.indptr, jnp.int32),
-                   indices=jnp.asarray(csr.indices, jnp.int32),
+                   indices=jnp.asarray(indices, jnp.int32),
                    rows=jnp.asarray(rows),
-                   values=jnp.asarray(csr.data, jnp.float32),
-                   n_rows=csr.n_rows, n_cols=csr.n_cols)
+                   values=jnp.asarray(values, jnp.float32),
+                   n_rows=csr.n_rows, n_cols=csr.n_cols,
+                   column_tile=column_tile)
 
     @property
     def arrays(self) -> tuple:
@@ -188,9 +218,31 @@ class CSRDevice:
 
     def matmul(self, x: jax.Array, interpret: bool | None = None) -> jax.Array:
         """Y = A @ X on unpadded x [n_cols, F] -> [n_rows, F]."""
-        interpret = default_interpret() if interpret is None else interpret
-        return csr_gather_spmm(*self.arrays, x, n_rows=self.n_rows,
-                               interpret=interpret)
+        return gather_pass(csr_gather_spmm, self.arrays, self.column_tile,
+                           x, n_rows=self.n_rows, interpret=interpret)
+
+
+def gather_pass(entry, arrays, column_tile: int | None, x, *args,
+                n_rows: int, interpret: bool | None = None, **kw):
+    """One SpMM pass over a CSR operand through ``entry`` (one of the
+    three ``csr_gather_spmm*``, kernels ``csr_window_spmm*``), at the
+    operand's tiles and the windows the chip's VMEM holds
+    (``window_rows``; off the chip one window holds the whole source).
+    More than one window needs the operand's tile-column order: a
+    row-ordered one raises where its source does not fit one window. Each
+    pass adds 1 to the counter ``spmm_window`` under the open span, while
+    tracing."""
+    interpret = default_interpret() if interpret is None else interpret
+    tm = column_tile or WINDOW_TILE
+    vmem = chip_vmem()
+    window = None if vmem is None else window_rows(x.shape[1], tm, vmem)
+    if column_tile is None and window is not None and window < x.shape[0]:
+        raise ValueError(
+            f"a row-ordered CSR operand reads {x.shape[0]} source rows, more "
+            f"than one VMEM window of {window}: build it with a column_tile")
+    count("spmm_window")
+    return entry(*arrays, x, *args, n_rows=n_rows, window=window,
+                 interpret=interpret, tm=tm, **kw)
 
 
 def build_bsr_pair(graph: CSRGraph, br: int = 8,
@@ -430,13 +482,12 @@ bsr_spmm_fused_pair.defvjp(_fused_pair_fwd, _fused_pair_bwd)
 # Fused-epilogue pair over CSR operands: the row-gather kernels
 # ---------------------------------------------------------------------------
 
-def _gather_fused(fwd_arrays, x, self_term, bias, alpha, n_rows, interpret,
+def _gather_fused(fwd_arrays, x, self_term, bias, alpha, geom, interpret,
                   activation):
-    """(y, mask|None) of the fused-epilogue gather kernel."""
-    interpret = default_interpret() if interpret is None else interpret
-    out = csr_gather_spmm_fused_epilogue(
-        *fwd_arrays, x, self_term, bias, alpha, n_rows=n_rows,
-        activation=activation, interpret=interpret)
+    """(y, mask|None) of the fused-epilogue pass over A."""
+    out = gather_pass(csr_gather_spmm_fused_epilogue, fwd_arrays, geom[2],
+                      x, self_term, bias, alpha, n_rows=geom[0],
+                      interpret=interpret, activation=activation)
     return out if activation == "relu" else (out, None)
 
 
@@ -447,34 +498,35 @@ def csr_spmm_fused_pair(fwd_arrays, bwd_arrays, x, self_term, bias, alpha,
 
     The row-gather sibling of ``bsr_spmm_fused_pair``: ``fwd_arrays`` and
     ``bwd_arrays`` are the (indptr, indices, rows, values) of A and Aᵀ,
-    ``geom`` is A's ``(n_rows, n_cols)``. Operands are unpadded: x [n_cols, F],
-    self_term [n_rows, F], bias [1, F]. The VJP gathers rows of the masked
-    cotangent mask ⊙ dY along Aᵀ (``csr_gather_spmm_masked``).
+    ``geom`` is A's ``(n_rows, n_cols)`` and the ``column_tile`` of A and
+    of Aᵀ. Operands are unpadded: x [n_cols, F], self_term [n_rows, F],
+    bias [1, F]. The VJP sums rows of the masked cotangent mask ⊙ dY along
+    Aᵀ (``csr_gather_spmm_masked``). Each pass runs through
+    ``gather_pass``.
     """
-    y, _ = _gather_fused(fwd_arrays, x, self_term, bias, alpha, geom[0],
+    y, _ = _gather_fused(fwd_arrays, x, self_term, bias, alpha, geom,
                          interpret, activation)
     return y
 
 
 def _csr_fused_fwd(fwd_arrays, bwd_arrays, x, self_term, bias, alpha, geom,
                    interpret, activation):
-    y, mask = _gather_fused(fwd_arrays, x, self_term, bias, alpha, geom[0],
+    y, mask = _gather_fused(fwd_arrays, x, self_term, bias, alpha, geom,
                             interpret, activation)
     return y, (fwd_arrays, bwd_arrays, mask, self_term, bias, alpha)
 
 
 def _csr_fused_bwd(geom, interpret, activation, res, dy):
     fwd_arrays, bwd_arrays, mask, self_term, bias, alpha = res
-    interpret = default_interpret() if interpret is None else interpret
     dy = dy.astype(jnp.float32)
     if activation == "relu":
         dz = dy * mask
-        dx = csr_gather_spmm_masked(*bwd_arrays, dy, mask, n_rows=geom[1],
-                                    interpret=interpret)
+        dx = gather_pass(csr_gather_spmm_masked, bwd_arrays, geom[3], dy,
+                         mask, n_rows=geom[1], interpret=interpret)
     else:
         dz = dy
-        dx = csr_gather_spmm(*bwd_arrays, dy, n_rows=geom[1],
-                             interpret=interpret)
+        dx = gather_pass(csr_gather_spmm, bwd_arrays, geom[3], dy,
+                         n_rows=geom[1], interpret=interpret)
     return (_zero_cotangents(fwd_arrays), _zero_cotangents(bwd_arrays),
             dx, *_epilogue_cotangents(dz, self_term, bias, alpha))
 
@@ -487,7 +539,7 @@ def build_gather_fused_epilogue(fwd: "CSRDevice", bwd: "CSRDevice",
     """Differentiable fused-epilogue closure over a (A, Aᵀ) CSRDevice pair,
     with ``build_fused_epilogue``'s calling convention. Rows are gathered
     whole, so there is no lane tile to choose."""
-    geom = (fwd.n_rows, fwd.n_cols)
+    geom = (fwd.n_rows, fwd.n_cols, fwd.column_tile, bwd.column_tile)
 
     def fused(u, self_term=None, bias=None, alpha=None, activation="none"):
         s = a = None
@@ -795,7 +847,11 @@ def build_gather_mha(fwd: "CSRDevice", bwd: "CSRDevice",
                      interpret: bool | None = None):
     """Differentiable fused-attention closure over a (A, Aᵀ) CSRDevice pair,
     with ``build_sparse_mha``'s calling convention: ``mha(z, a_src, a_dst)``
-    on ``z [n_src, H, Dh]`` -> ``[n_dst, H, Dh]``."""
+    on ``z [n_src, H, Dh]`` -> ``[n_dst, H, Dh]``. The kernels run row by
+    row: both operands must be in row order (``column_tile`` None)."""
+    if fwd.column_tile is not None or bwd.column_tile is not None:
+        raise ValueError("the gather-attention kernels need row-ordered "
+                         "CSR operands (column_tile=None)")
     geom = (fwd.n_rows, fwd.n_cols)
 
     def mha(z, a_src, a_dst):

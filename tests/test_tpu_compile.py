@@ -1,7 +1,7 @@
 """Mosaic compiles every Pallas kernel of the main path for a described TPU
 v5e, at ogbn-arxiv's published size (169,343 nodes; 917,090 blocks at the
 (8, 128) tile, 1,081,262 at (8, 16); 1,320,039 nonzeros for the CSR
-row-gather kernels).
+row-gather and window kernels).
 
 Nothing runs: each test lowers and compiles against the topology only, so
 it catches what interpret mode cannot — SMEM overflow from scalar-prefetched
@@ -15,7 +15,8 @@ import re
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import AbstractDevice, AbstractMesh, SingleDeviceSharding
 
 from repro.kernels.bsr_attention import (
     bsr_attention_bwd_col,
@@ -33,10 +34,13 @@ from repro.kernels.csr_gather_attention import (
     csr_gather_attention_fwd,
 )
 from repro.kernels.csr_gather_spmm import (
+    WINDOW_TILE,
     csr_gather_spmm,
     csr_gather_spmm_fused_epilogue,
     csr_gather_spmm_masked,
+    window_rows,
 )
+from repro.kernels import ops as kops
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.fused_adam import fused_adam
 from repro.kernels.ops import feature_tile
@@ -75,9 +79,22 @@ def shape(topo):
     cc.reset_cache()
 
 
+@pytest.fixture(scope="module")
+def vmem(topo):
+    """The VMEM bytes ``pltpu.get_tpu_info`` reports for the described
+    chip: the capacity the window kernels size their windows by."""
+    d = topo.devices[0]
+    chip = AbstractMesh((1,), ("x",), abstract_device=AbstractDevice(
+        device_kind=d.device_kind, num_cores=d.num_cores))
+    with jax.sharding.use_abstract_mesh(chip):
+        return pltpu.get_tpu_info().vmem_capacity_bytes
+
+
 def _compile(fn, *args):
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    return text
 
 
 def _stream(shape, n_blocks, br, bc):
@@ -121,32 +138,70 @@ def _csr(shape, n=N_NODES, nnz=NNZ):
     return shape((n + 1,), jnp.int32), idx, idx, shape((nnz,))
 
 
+# each SpMM entry point, its keyword arguments and its arguments after the
+# CSR, by the entry point's name
 GATHER = {
     "csr_gather_spmm": lambda shape, n, f: (
-        lambda *a: csr_gather_spmm(*a, n_rows=n), shape((n, f))),
+        csr_gather_spmm, {}, shape((n, f))),
     "csr_gather_spmm_fused_epilogue": lambda shape, n, f: (
-        lambda *a: csr_gather_spmm_fused_epilogue(
-            *a, n_rows=n, activation="relu"),
+        csr_gather_spmm_fused_epilogue, {"activation": "relu"},
         shape((n, f)), shape((n, f)), shape((f,)), shape(())),
     "csr_gather_spmm_masked": lambda shape, n, f: (
-        lambda *a: csr_gather_spmm_masked(*a, n_rows=n),
+        csr_gather_spmm_masked, {}, shape((n, f)), shape((n, f))),
+}
+
+
+# the window path of each entry point, by its kernel's name
+WINDOW = {
+    "csr_window_spmm": lambda shape, n, f, w: (
+        lambda *a: csr_gather_spmm(*a, n_rows=n, window=w,
+                                   tm=WINDOW_TILE), shape((n, f))),
+    "csr_window_spmm_fused_epilogue": lambda shape, n, f, w: (
+        lambda *a: csr_gather_spmm_fused_epilogue(
+            *a, n_rows=n, window=w, tm=WINDOW_TILE, activation="relu"),
+        shape((n, f)), shape((n, f)), shape((f,)), shape(())),
+    "csr_window_spmm_masked": lambda shape, n, f, w: (
+        lambda *a: csr_gather_spmm_masked(*a, n_rows=n, window=w,
+                                          tm=WINDOW_TILE),
         shape((n, f)), shape((n, f))),
 }
 
 
-def _gather(shape, name, n, nnz, f):
-    """``(fn, *args)`` of one row-gather kernel over a CSR of ``n`` rows."""
-    fn, *rest = GATHER[name](shape, n, f)
-    return (fn, *_csr(shape, n, nnz), *rest)
+def _window_calls(text):
+    """The Mosaic custom calls of compiled HLO, one line each."""
+    return [line for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
 
 
 @pytest.mark.parametrize("f", [256, 40])
 @pytest.mark.parametrize("name", list(GATHER))
-def test_csr_gather_compiles(shape, name, f):
-    """The row-gather kernels at arxiv's size: SMEM holds one window of
-    row pointers and two chunks of indices whatever nnz is, and the gather
-    buffer two chunks of full-width rows in VMEM."""
-    _compile(*_gather(shape, name, N_NODES, NNZ, f))
+def test_csr_gather_compiles(shape, vmem, monkeypatch, name, f):
+    """Each SpMM entry point at arxiv's size as the program dispatches it
+    (``kops.gather_pass`` over a tile-column operand), with the windows
+    the described chip's VMEM holds: two at 256 lanes, one at 40. SMEM
+    holds one window of tile bounds and two chunks of indices whatever
+    nnz is."""
+    monkeypatch.setattr(kops, "chip_vmem", lambda: vmem)
+    entry, kw, x, *rest = GATHER[name](shape, N_NODES, f)
+
+    def fn(*a):
+        return kops.gather_pass(entry, a[:4], WINDOW_TILE, a[4], *a[5:],
+                                n_rows=N_NODES, interpret=False, **kw)
+
+    text = _compile(fn, *_csr(shape), x, *rest)
+    assert len(_window_calls(text)) == (2 if f == 256 else 1)
+
+
+@pytest.mark.parametrize("f,n_windows", [(256, 2), (128, 1)])
+@pytest.mark.parametrize("name", list(WINDOW))
+def test_csr_window_compiles(shape, vmem, name, f, n_windows):
+    """The window kernels at arxiv's size, with the window the chip's VMEM
+    holds beside the tiles (``window_rows``) and the ``vmem_limit_bytes``
+    that sets: one call per window, each holding its window of ``x`` in
+    VMEM."""
+    fn, *rest = WINDOW[name](shape, N_NODES, f,
+                             window_rows(f, WINDOW_TILE, vmem))
+    assert len(_window_calls(_compile(fn, *_csr(shape), *rest))) == n_windows
 
 
 GATHER_ATTENTION = {
@@ -257,9 +312,10 @@ KERNELS = {
 
 
 KERNELS.update({
-    name: lambda shape, stream, n, _name=name: _gather(shape, _name, n, 4096,
-                                                       128)
-    for name in GATHER})
+    name: lambda shape, stream, n, _name=name: (
+        lambda fn, *rest: (fn, *_csr(shape, n, 4096), *rest))(
+            *WINDOW[_name](shape, n, 128, n // 2))
+    for name in WINDOW})
 KERNELS.update({
     name: lambda shape, stream, n, _name=name: _gather_attention(
         shape, _name, n, 4096, 120)
@@ -270,8 +326,9 @@ KERNELS.update({
 def test_kernel_lowers_under_its_own_name(shape, name):
     """Every Mosaic custom call of a kernel carries the kernel's
     ``kernel_name`` (BSR: one call per window, 64 blocks in windows of 16;
-    CSR: one call), so traces and compiled HLO find it by that name; none
-    is ``kernel``."""
+    CSR SpMM: one call per source window of 512 rows; CSR attention: one
+    call), so traces and
+    compiled HLO find it by that name; none is ``kernel``."""
     n = 1024
     stream = _stream(shape, 64, 8, 128)
     fn, *args = KERNELS[name](shape, stream, n)
