@@ -1,4 +1,4 @@
-"""Block-tile autotune sweep for the fused-epilogue SpMM (DESIGN.md §8).
+"""Block-tile sweep for the fused-epilogue SpMM (DESIGN.md §8).
 
 Two sweeps, both on the XLA inner (compiled block einsum — the CPU
 wall-time stand-in; the Pallas interpreter would measure Python, not the
@@ -37,7 +37,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import csv_row, time_call
-from repro.core.layout import cached_layout
 from repro.core.lowering import lower
 from repro.graph.datasets import generate_dataset
 from repro.kernels import ops as kops
@@ -47,9 +46,6 @@ from repro.models.gnn import GNNConfig, GNNModel
 DATASET = "nell"          # 99%-sparse features: exercises the sparse input path
 SCALE = 0.004
 HIDDEN = 32
-# fallback grid when no autotuned layout is cached; when bench_layout (or
-# any `layout="auto"` lowering) has cached a winner for this graph, the
-# fused-vs-unfused comparison runs at that layout instead
 BR_BC_GRID = [(8, 32), (8, 128), (16, 64)]
 BF_SWEEP = [32, 64, 128]
 JSON_PATH = os.path.join(os.path.dirname(os.path.dirname(
@@ -121,29 +117,12 @@ def run() -> list[str]:
     record = {"dataset": DATASET, "n_nodes": int(n),
               "nnz": int(ds.graph.nnz), "grid": [], "bf_sweep": []}
 
-    # the autotuned layout, when bench_layout (run.py orders it first) or
-    # any `layout="auto"` lowering has cached one for this exact graph:
-    # fused-vs-unfused is then compared at the best layout, not a
-    # hardcoded grid point
-    tuned = cached_layout(ds.graph, HIDDEN, backend="xla", fused=True)
-    if tuned is not None:
-        grid = [(tuned.br, tuned.bc)]
-        record["autotuned"] = {"order": tuned.order, "br": tuned.br,
-                               "bc": tuned.bc, "bf": tuned.bf,
-                               "source": tuned.source}
-    else:
-        grid = BR_BC_GRID
-
     best = None
-    for br, bc in grid:
-        # a LayoutPlan carries its own tile, so br/bc must not also be
-        # passed (lower raises on the conflict)
-        tile_kw = ({"layout": tuned} if tuned is not None
-                   else {"br": br, "bc": bc})
+    for br, bc in BR_BC_GRID:
         epochs = {}
         for fused_flag in (True, False):
             plan = lower(cfg, ds.graph, ds.features, engine="xla",
-                         fuse_epilogue=fused_flag, **tile_kw)
+                         fuse_epilogue=fused_flag, br=br, bc=bc)
             model = GNNModel(cfg, ds.graph, plan=plan)
             params = model.init(jax.random.PRNGKey(0))
             epoch = _epoch_fn(model, x, labels, mask)

@@ -3,12 +3,8 @@
 Prints ``name,us_per_call,derived`` CSV rows (plus a header per section).
 
   bench_throughput  — Fig 2/3: fused vs gather-scatter per-epoch time
-  bench_layout      — §9: reorder × tile sweep + autotune vs PR-4
-                      defaults; emits BENCH_layout.json and warms the
-                      layout cache bench_fusion consults
   bench_fusion      — §8: (br, bc, bf) tile sweep × fused-vs-unfused
-                      epilogue at the autotuned layout when cached;
-                      emits BENCH_fusion.json
+                      epilogue; emits BENCH_fusion.json
   bench_attention   — §10: fused BSR flash-attention vs the gather
                       edge-softmax (GAT epochs + op-level, 1/4 heads);
                       emits BENCH_attention.json
@@ -41,7 +37,6 @@ def main() -> None:
         bench_attention,
         bench_distributed,
         bench_fusion,
-        bench_layout,
         bench_memory,
         bench_moe_dispatch,
         bench_partitioner,
@@ -56,9 +51,7 @@ def main() -> None:
 
     print("name,us_per_call,derived")
     failed = []
-    # bench_layout runs before bench_fusion: it writes the layout cache
-    # entry bench_fusion reads for its autotuned-tile grid point
-    for mod in (bench_throughput, bench_layout, bench_fusion,
+    for mod in (bench_throughput, bench_fusion,
                 bench_attention, bench_memory, bench_sampling,
                 bench_serving, bench_partitioner, bench_sparsity,
                 bench_distributed, bench_moe_dispatch, bench_resilience,

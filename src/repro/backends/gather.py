@@ -53,8 +53,7 @@ class GatherBackend(Backend):
         return jax.ops.segment_sum(msgs, operand.dst, num_segments=operand.n_rows)
 
     def sparse_mha(self, fwd_operand, bwd_operand, *,
-                   interpret: Optional[bool] = None,
-                   bf: Optional[int] = None):
+                   interpret: Optional[bool] = None):
         """Attention on this backend *is* the gather path — serve the
         ``sparse_mha`` contract over the edge-list operand so the vocabulary
         stays complete (and the fused/gather benchmark has a peer to call),

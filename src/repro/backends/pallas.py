@@ -65,30 +65,26 @@ class PallasBackend(Backend):
         return operand.matmul(x, interpret=interpret)
 
     def spmm_fused_epilogue(self, fwd_operand, bwd_operand, *,
-                            interpret: Optional[bool] = None,
-                            bf: Optional[int] = None):
+                            interpret: Optional[bool] = None):
         """The native fused kernels: epilogue applied in VMEM where a row
         completes; the VJP folds the activation mask into the transposed
-        SpMM (``bsr_spmm_masked``, ``csr_gather_spmm_masked``). On BSR
-        operands ``bf`` pins the MXU lane tile (autotuned layouts) and
-        ``None`` keeps the per-call ``feature_tile`` policy; CSR operands
+        SpMM (``bsr_spmm_masked``, ``csr_gather_spmm_masked``). BSR
+        operands take the per-call ``feature_tile`` lane tile; CSR operands
         gather whole rows and take no lane tile."""
         if fwd_operand.format == "gather":
             return kops.build_gather_fused_epilogue(fwd_operand, bwd_operand,
                                                     interpret=interpret)
         return kops.build_fused_epilogue(fwd_operand, bwd_operand, "pallas",
-                                         interpret=interpret, bf=bf)
+                                         interpret=interpret)
 
     def sparse_mha(self, fwd_operand, bwd_operand, *,
-                   interpret: Optional[bool] = None,
-                   bf: Optional[int] = None):
+                   interpret: Optional[bool] = None):
         """The native fused attention kernels (DESIGN.md §10): online
         segment softmax + aggregation in one VMEM pass, recompute VJP from
-        the saved per-row (max, denominator) stats. On BSR operands ``bf``
-        tiles the per-head lane dim when the cached layout asks for it; CSR
-        operands gather whole rows (``csr_gather_attention``)."""
+        the saved per-row (max, denominator) stats, over BSR blocks or CSR
+        row gathers (``csr_gather_attention``)."""
         if fwd_operand.format == "gather":
             return kops.build_gather_mha(fwd_operand, bwd_operand,
                                          interpret=interpret)
         return kops.build_sparse_mha(fwd_operand, bwd_operand, "pallas",
-                                     interpret=interpret, bf=bf)
+                                     interpret=interpret)

@@ -197,7 +197,7 @@ class Backend:
     def build_spmm_operand(self, csr: CSRGraph, br: int = 8,
                            bc: Optional[int] = None, fmt: str = "bsr"):
         """Build this backend's sparse operand at the given BSR tile.
-        ``bc=None`` is the un-autotuned fallback: adaptive to ``n_cols``
+        ``bc=None`` is the default: adaptive to ``n_cols``
         (``graph.csr.adaptive_bc``) so small graphs stop lane-padding; the
         lowering pass passes the ``LayoutPlan``'s tile explicitly.
         ``fmt`` picks the format where a backend has two (Pallas):
@@ -241,8 +241,7 @@ class Backend:
         return edge_softmax_aggregate(z, a_src, a_dst, src, dst, n_nodes)
 
     def sparse_mha(self, fwd_operand, bwd_operand, *,
-                   interpret: Optional[bool] = None,
-                   bf: Optional[int] = None) -> Optional[Callable]:
+                   interpret: Optional[bool] = None) -> Optional[Callable]:
         """Differentiable fused multi-head attention ``(z [N,H,Dh], a_src,
         a_dst) -> [n_dst,H,Dh]`` over a pre-built operand pair, or ``None``
         when this backend has no fused attention lowering (the planner then
@@ -250,12 +249,10 @@ class Backend:
         return None
 
     def spmm_attention(self, fwd_operand, bwd_operand, *,
-                       interpret: Optional[bool] = None,
-                       bf: Optional[int] = None) -> Optional[Callable]:
+                       interpret: Optional[bool] = None) -> Optional[Callable]:
         """``sparse_mha`` in the trainers' calling convention:
         ``(z [N, H*Dh], a_src, a_dst, heads) -> [n_dst, H, Dh]``."""
-        mha = self.sparse_mha(fwd_operand, bwd_operand, interpret=interpret,
-                              bf=bf)
+        mha = self.sparse_mha(fwd_operand, bwd_operand, interpret=interpret)
         if mha is None:
             return None
 
@@ -290,17 +287,14 @@ class Backend:
 
     def spmm_fused_epilogue(
         self, fwd_operand, bwd_operand, *, interpret: Optional[bool] = None,
-        bf: Optional[int] = None,
     ) -> Callable:
         """Differentiable ``(u, self_term, bias, alpha, activation) ->
         act(A @ u + alpha * self_term + bias)`` over the pre-built pair.
 
         Base implementation: the transposed-VJP spmm composed with
-        ``apply_epilogue`` — the universal (gather/edge-list) lowering,
-        which has no lane tiling (``bf`` is accepted for signature parity
-        and ignored). Backends with a native fused kernel (Pallas) or a
-        compiled layout that benefits from the shared custom VJP (XLA)
-        override this and honour an autotuned ``bf``.
+        ``apply_epilogue`` — the universal (gather/edge-list) lowering.
+        Backends with a native fused kernel (Pallas) or a compiled layout
+        that benefits from the shared custom VJP (XLA) override this.
         """
         return compose_epilogue(
             self.spmm_transposed_vjp(fwd_operand, bwd_operand,

@@ -37,26 +37,22 @@ class XLABackend(Backend):
         return operand.matmul_ref(x)
 
     def spmm_fused_epilogue(self, fwd_operand, bwd_operand, *,
-                            interpret: Optional[bool] = None,
-                            bf: Optional[int] = None):
+                            interpret: Optional[bool] = None):
         """lax-composed fused epilogue over the same custom VJP as the
         Pallas kernel (``kernels/ref.py:bsr_spmm_fused_ref`` inner): XLA
         fuses the epilogue chain into the block einsum's consumer, and the
         backward applies the saved activation mask as one fused multiply
         before the transposed SpMM — CPU parity and wall-time benchmarks
-        measure the identical algebra. ``bf`` only moves the padding
-        boundary here (no lane hardware), but autotuned plans thread it
-        anyway so both inners run the tile the tuner measured."""
+        measure the identical algebra."""
         return kops.build_fused_epilogue(fwd_operand, bwd_operand, "xla",
-                                         interpret=interpret, bf=bf)
+                                         interpret=interpret)
 
     def sparse_mha(self, fwd_operand, bwd_operand, *,
-                   interpret: Optional[bool] = None,
-                   bf: Optional[int] = None):
+                   interpret: Optional[bool] = None):
         """Fused attention over the same custom VJP as the Pallas kernel,
         with the lax-composed block reference as the executor
         (``kernels/ref.py:bsr_attention_ref`` / ``bsr_attention_bwd_ref``) —
         identical recompute-from-(m, l) algebra, so parity holds across
         inners and plans bind one primitive name."""
         return kops.build_sparse_mha(fwd_operand, bwd_operand, "xla",
-                                     interpret=interpret, bf=bf)
+                                     interpret=interpret)

@@ -3,8 +3,8 @@
 - sparsity.py    — Alg 1 sparsity-aware execution engine (Eq. 1-5)
 - aggregate.py   — fused neighbour aggregation (no O(|E|·F) edge tensors),
                    with custom VJP using the pre-transposed graph (CSC analog)
-- layout.py      — layout-optimization stage: reorder selection + cached
-                   BSR tile autotuning (LayoutPlan, threaded by lowering.py)
+- layout.py      — layout stage: node-order selection, the BSR tile and
+                   the operand format (LayoutPlan, threaded by lowering.py)
 - partitioner.py — Alg 4 hierarchical constraint-relaxation partitioner
 - halo.py        — distributed halo exchange (MPI backend analog, shard_map)
 - pipeline.py    — pipelined backward: overlap dW psum with dX compute
@@ -18,4 +18,4 @@ from repro.core.sparsity import (
     calibrate_gamma,
 )
 from repro.core.partitioner import hierarchical_partition, PartitionResult
-from repro.core.layout import LayoutPlan, cached_layout, plan_layout
+from repro.core.layout import LayoutPlan

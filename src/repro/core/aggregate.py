@@ -125,7 +125,6 @@ def make_fused_aggregate(
     bc: int | None = None,
     interpret: bool | None = None,
     engine: "str | Backend | None" = None,  # registry name; None = auto-select
-    bf: int | None = None,
     build_attention: bool = False,
     fmt: str = "auto",
 ) -> FusedGraphOp:
@@ -133,7 +132,7 @@ def make_fused_aggregate(
     operand pair on the selected backend, return a differentiable fused
     operator (``spmm_transposed_vjp`` from the registry). ``bc=None`` takes
     the adaptive fallback width; the lowering pass passes a ``LayoutPlan``'s
-    tile (and its ``bf`` lane tile for the fused-epilogue operator).
+    tile.
 
     ``build_attention`` additionally binds the backend's fused
     ``spmm_attention`` over the same pair (attention ignores the edge
@@ -161,7 +160,7 @@ def make_fused_aggregate(
             fwd, bwd = _operand_pair(backend, weighted, br, bc, fmt,
                                      attention=True)
             agg_attention = backend.spmm_attention(fwd, bwd,
-                                                   interpret=interpret, bf=bf)
+                                                   interpret=interpret)
 
         return FusedGraphOp(
             aggregate=agg_max, n_nodes=n, aggregation="max",
@@ -175,12 +174,10 @@ def make_fused_aggregate(
     fwd, bwd = _operand_pair(backend, weighted, br, bc, fmt,
                              attention=build_attention)
     agg = backend.spmm_transposed_vjp(fwd, bwd, interpret=interpret)
-    agg_epilogue = backend.spmm_fused_epilogue(fwd, bwd, interpret=interpret,
-                                               bf=bf)
+    agg_epilogue = backend.spmm_fused_epilogue(fwd, bwd, interpret=interpret)
     agg_attention = None
     if build_attention:
-        agg_attention = backend.spmm_attention(fwd, bwd, interpret=interpret,
-                                               bf=bf)
+        agg_attention = backend.spmm_attention(fwd, bwd, interpret=interpret)
 
     return FusedGraphOp(
         aggregate=agg,

@@ -163,8 +163,9 @@ class GNNProgram:
 
         ``engine`` names a registered backend ("pallas" | "xla" | "gather");
         ``None`` auto-selects the best available one for this platform.
-        ``layout="auto"`` additionally runs the layout-optimization stage
-        (graph reordering + cached tile autotuning, DESIGN.md §9).
+        ``layout`` reorders the graph's nodes (DESIGN.md §9): ``"degree"``
+        or ``"rcm"`` by name, ``"auto"`` only where a reorder cuts the BSR
+        block count by at least 10%; ``None`` keeps the given order.
         ``fuse_attention=False`` drops GAT/GT back to the gather-style
         segment softmax instead of the fused BSR kernel (DESIGN.md §10).
         ``validate`` selects the plan-contract verification depth

@@ -23,6 +23,12 @@ sparse-profitable estimate record the decision and fall back to the dense
 primitive, with the fallback noted in the plan — the plan never lies about
 what will execute.
 
+Three passes lower a spec, one per graph source: ``lower`` (the full
+graph), ``lower_sampled`` (neighbour-sampled mini-batches) and
+``lower_distributed`` (rank-partitioned). Each keeps only its graph-source
+prologue and builds its ``LayerPlan`` records through one planner,
+``_plan_layers`` (DESIGN.md §3).
+
 ``GNNModel.apply`` executes plans directly; nothing monkey-patches model
 methods anymore.
 """
@@ -43,7 +49,6 @@ from repro.core.layout import (
     _select_order,
     default_layout,
     operand_format,
-    plan_layout,
 )
 from repro.core.sparsity import (
     PAPER_GAMMA_DEFAULT,
@@ -403,18 +408,7 @@ def lower_sampled(
         raise ValueError(
             f"need one fanout per layer ({config.n_layers}), got {fanouts!r}")
 
-    if isinstance(layout, LayoutPlan):
-        lp = dataclasses.replace(
-            layout, br=int(br), bc=int(bc), bf=0, n_blocks=0,
-            padding_waste=0.0, source="sampled")
-    else:
-        if layout is None:
-            mode, g_r, perm, inv = "none", graph, None, None
-        else:
-            mode, g_r, perm, inv = _select_order(graph, layout)
-        lp = LayoutPlan(order=mode, br=int(br), bc=int(bc), perm=perm,
-                        inv_perm=inv, source="sampled",
-                        reordered_graph=g_r if mode != "none" else None)
+    lp = _resolve_layout(graph, layout, int(br), int(bc), source="sampled")
     if lp.permutes:
         graph = (lp.reordered_graph if lp.reordered_graph is not None
                  else permute_graph(graph, lp.inv_perm))
@@ -460,63 +454,32 @@ def lower_sampled(
     else:
         agg_primitive = f"{backend.name}.spmm_transposed_vjp"
 
-    layers: list[LayerPlan] = []
-    for i in range(config.n_layers):
-        d_in, d_out = dims[i], dims[i + 1]
-        if i == 0:
-            decision = decide_execution_path_from_stats(
-                s_frontier, int(frontier0.shape[0]), d_in, d_out, gamma=gamma)
-        else:
-            s_est = estimate_activation_sparsity(config.activation)
-            decision = decide_execution_path_from_stats(
-                s_est, int(frontier0.shape[0]), d_in, d_out, gamma=gamma)
+    def bind_sparse():
+        # per-batch feature matrices are runtime values: the sampler streams
+        # COO operands in the gather backend's edge-list layout, capped by
+        # the template's measured density
+        caps = [
+            max(min(int(np.ceil(b.node_caps[0] * dims[0]
+                                * (1.0 - s_frontier) * feat_slack)),
+                    b.node_caps[0] * dims[0]), 1)
+            for b in sampler.buckets
+        ]
+        sampler.set_feature_caps(caps)
+        return ("gather.feature_matmul_sparse", None,
+                f"per-batch COO operand streamed by the sampler "
+                f"(slack={feat_slack:g})")
 
-        path, primitive, note = "dense", f"{backend.name}.feature_matmul_dense", ""
-        if i == 0 and decision.mode == "sparse":
-            expressible, expr_note = _sparse_expressible(kind)
-            if not use_sparse_input:
-                note = "sparse profitable but disabled (use_sparse_input=False)"
-            elif not expressible:
-                note = expr_note
-            else:
-                # per-batch feature matrices are runtime values: the sampler
-                # streams COO operands in the gather backend's edge-list
-                # layout, capped by the template's measured density
-                f_dim = dims[0]
-                caps = [
-                    max(min(int(np.ceil(b.node_caps[0] * f_dim
-                                        * (1.0 - s_frontier) * feat_slack)),
-                            b.node_caps[0] * f_dim), 1)
-                    for b in sampler.buckets
-                ]
-                sampler.set_feature_caps(caps)
-                path = "sparse"
-                primitive = "gather.feature_matmul_sparse"
-                note = (f"per-batch COO operand streamed by the sampler "
-                        f"(slack={feat_slack:g})")
-                if expr_note:
-                    note += f"; {expr_note}"
-        elif decision.mode == "sparse":
-            note = ("sparse profitable but activations are runtime values; "
-                    "no pre-built operand — dense fallback")
-
-        epilogue = None
-        if emit_epilogue:
-            epilogue = _epilogue_binding(
-                config, is_last=(i == config.n_layers - 1),
-                sparse_path=(path == "sparse"))
-        attention = None
-        if is_attn:
-            attention = _attention_binding(
-                kind, config.gat_heads, d_out, i == config.n_layers - 1,
-                emit_attn)
-
-        layers.append(LayerPlan(
-            index=i, op_kind=kind, d_in=d_in, d_out=d_out,
-            feature_path=path, primitive=primitive,
-            agg_primitive=agg_primitive, decision=decision, note=note,
-            epilogue=epilogue, attention=attention, layout=lp,
-        ))
+    n_frontier = int(frontier0.shape[0])
+    layers = _plan_layers(
+        config, gamma=gamma, n_rows=n_frontier,
+        decision0=decide_execution_path_from_stats(
+            s_frontier, n_frontier, dims[0], dims[1], gamma=gamma),
+        sparse_input=use_sparse_input,
+        off_note="sparse profitable but disabled (use_sparse_input=False)",
+        bind_sparse=bind_sparse,
+        dense_primitive=f"{backend.name}.feature_matmul_dense",
+        agg_primitive=agg_primitive, epilogue=emit_epilogue,
+        attention_fused=emit_attn, layout=lp)
 
     plan = SampledModelPlan(
         layers=layers, backend=backend.name, gamma=gamma, arch=kind,
@@ -641,7 +604,7 @@ def lower_distributed(
     # permutation is baked into the data distribution, so no
     # trainer-boundary perm — loss and grads are order-invariant)
     lp = LayoutPlan(order=getattr(dist, "reorder", "none"),
-                    br=dist.br, bc=dist.bc, bf=0, source="distributed")
+                    br=dist.br, bc=dist.bc, source="distributed")
 
     n_valid = (np.asarray(dist.n_valid) if dist.n_valid is not None
                else np.full(P, dist.n_local))
@@ -654,77 +617,49 @@ def lower_distributed(
         nnz_total += nnz
     pooled_s = 1.0 - nnz_total / max(int(n_valid.sum()) * f_dim, 1)
 
-    # per-rank Alg-1 decisions for layer 0; pooled record kept on the plan
-    rank_decisions = [
+    # per-rank Alg-1 decisions for layer 0 (the sparse path needs them all);
+    # the pooled decision is the plan's record
+    n_sparse = sum(
         decide_execution_path_from_stats(
-            per_rank_s[p], int(n_valid[p]), dims[0], dims[1], gamma=gamma)
-        for p in range(P)
-    ]
-    all_sparse = all(d.mode == "sparse" for d in rank_decisions)
+            per_rank_s[p], int(n_valid[p]), dims[0], dims[1], gamma=gamma
+        ).mode == "sparse"
+        for p in range(P))
 
     feat_fwd = feat_bwd = None
     f_pad = 0
-    layers: list[LayerPlan] = []
-    for i in range(config.n_layers):
-        d_in, d_out = dims[i], dims[i + 1]
-        if i == 0:
-            decision = decide_execution_path_from_stats(
-                pooled_s, int(n_valid.sum()), d_in, d_out, gamma=gamma)
-        else:
-            s_est = estimate_activation_sparsity(config.activation)
-            decision = decide_execution_path_from_stats(
-                s_est, int(n_valid.sum()), d_in, d_out, gamma=gamma)
 
-        path, primitive, note = "dense", "distributed.feature_matmul_dense", ""
-        if i == 0 and decision.mode == "sparse":
-            expressible, expr_note = _sparse_expressible(kind)
-            if not use_sparse_input:
-                note = "sparse profitable but disabled (use_sparse_input=False)"
-            elif not expressible:
-                note = expr_note
-            elif not all_sparse:
-                note = (f"mixed fleet: {sum(d.mode == 'sparse' for d in rank_decisions)}"
-                        f"/{P} ranks sparse — SPMD-uniform dense fallback")
-            else:
-                # build the stacked per-rank sparse operands once, here
-                br, bc = dist.br, dist.bc
-                mult = int(np.lcm(br, bc))
-                f_pad = -(-f_dim // mult) * mult
-                fwd_stack, bwd_stack = [], []
-                for p in range(P):
-                    x_csr = csr_from_dense(feats[p])
-                    x_csr = dataclasses.replace(x_csr, n_cols=f_pad)
-                    fwd_stack.append(csr_to_bsr(x_csr, br=br, bc=bc))
-                    bwd_stack.append(csr_to_bsr(x_csr.transpose(), br=br, bc=bc))
-                feat_fwd = stack_bsr_matrices(fwd_stack, br, bc)
-                feat_bwd = stack_bsr_matrices(bwd_stack, br, bc)
-                path = "sparse"
-                primitive = "distributed.dist_feature_matmul_sparse"
-                note = (f"per-rank BSR(X_local); s in "
-                        f"[{per_rank_s.min():.3f}, {per_rank_s.max():.3f}]")
-                if expr_note:
-                    note += f"; {expr_note}"
-        elif decision.mode == "sparse":
-            note = ("sparse profitable but activations are runtime values; "
-                    "no pre-built operand — dense fallback")
+    def bind_sparse():
+        # build the stacked per-rank sparse operands once, here
+        nonlocal feat_fwd, feat_bwd, f_pad
+        br, bc = dist.br, dist.bc
+        mult = int(np.lcm(br, bc))
+        f_pad = -(-f_dim // mult) * mult
+        fwd_stack, bwd_stack = [], []
+        for p in range(P):
+            x_csr = csr_from_dense(feats[p])
+            x_csr = dataclasses.replace(x_csr, n_cols=f_pad)
+            fwd_stack.append(csr_to_bsr(x_csr, br=br, bc=bc))
+            bwd_stack.append(csr_to_bsr(x_csr.transpose(), br=br, bc=bc))
+        feat_fwd = stack_bsr_matrices(fwd_stack, br, bc)
+        feat_bwd = stack_bsr_matrices(bwd_stack, br, bc)
+        return ("distributed.dist_feature_matmul_sparse", None,
+                f"per-rank BSR(X_local); s in "
+                f"[{per_rank_s.min():.3f}, {per_rank_s.max():.3f}]")
 
-        epilogue = None
-        if emit_epilogue:
-            epilogue = _epilogue_binding(
-                config, is_last=(i == config.n_layers - 1),
-                sparse_path=(path == "sparse"))
-        attention = None
-        if is_attn:
-            attention = _attention_binding(
-                kind, config.gat_heads, d_out, i == config.n_layers - 1,
-                emit_attn)
-
-        layers.append(LayerPlan(
-            index=i, op_kind=kind, d_in=d_in, d_out=d_out,
-            feature_path=path, primitive=primitive,
-            agg_primitive=agg_primitive, decision=decision, note=note,
-            epilogue=epilogue, attention=attention, layout=lp,
-        ))
+    n_rows = int(n_valid.sum())
+    layers = _plan_layers(
+        config, gamma=gamma, n_rows=n_rows,
+        decision0=decide_execution_path_from_stats(
+            pooled_s, n_rows, dims[0], dims[1], gamma=gamma),
+        sparse_input=use_sparse_input,
+        off_note="sparse profitable but disabled (use_sparse_input=False)",
+        veto=("" if n_sparse == P else
+              f"mixed fleet: {n_sparse}/{P} ranks sparse — SPMD-uniform "
+              f"dense fallback"),
+        bind_sparse=bind_sparse,
+        dense_primitive="distributed.feature_matmul_dense",
+        agg_primitive=agg_primitive, epilogue=emit_epilogue,
+        attention_fused=emit_attn, layout=lp)
 
     plan = DistributedModelPlan(
         layers=layers, backend="distributed", inner=inner_name, gamma=gamma,
@@ -801,56 +736,133 @@ def _sparse_expressible(kind: str) -> tuple[bool, str]:
     return False, f"no sparse lowering for {kind}"
 
 
+def _plan_layers(
+    config,
+    *,
+    gamma: float,
+    n_rows: int,
+    decision0: SparsityDecision,
+    sparse_input: bool,
+    off_note: str,
+    bind_sparse: Callable[[], tuple[str, Optional[Callable], str]],
+    dense_primitive: str,
+    agg_primitive: str,
+    epilogue: bool,
+    attention_fused: bool,
+    layout: LayoutPlan,
+    veto: str = "",
+    operand: str = "",
+    operand_note: str = "",
+) -> list[LayerPlan]:
+    """The per-layer planner of all three lowering passes.
+
+    Each pass brings what its graph source decides: layer 0's Alg-1
+    ``decision0`` on that source's input statistics, the row count
+    ``n_rows`` the hidden-layer estimates are taken at, and
+    ``bind_sparse`` — called at most once, when layer 0 takes the sparse
+    input path — which builds that source's sparse feature operand and
+    returns ``(primitive, sparse_xw, note)``. Layer 0 binds it when its
+    decision is sparse, ``sparse_input`` is on (else ``off_note``), the
+    arch can express it (``_sparse_expressible``) and the pass raised no
+    ``veto`` note. Hidden layers record their estimate and run dense: their
+    inputs are runtime values with no pre-built operand.
+
+    Every layer shares the aggregation primitive, the layout and the
+    operand format; ``epilogue`` and ``attention_fused`` bind the per-layer
+    epilogue and attention records.
+    """
+    kind, dims = config.kind, list(config.layer_dims)
+    last = config.n_layers - 1
+    layers: list[LayerPlan] = []
+    for i in range(config.n_layers):
+        d_in, d_out = dims[i], dims[i + 1]
+        decision = decision0 if i == 0 else decide_execution_path_from_stats(
+            estimate_activation_sparsity(config.activation), n_rows, d_in,
+            d_out, gamma=gamma)
+        path, primitive, sparse_xw, note = "dense", dense_primitive, None, ""
+        if decision.mode == "sparse":
+            expressible, expr_note = _sparse_expressible(kind)
+            if i > 0:
+                note = ("sparse profitable but activations are runtime "
+                        "values; no pre-built operand — dense fallback")
+            elif not sparse_input:
+                note = off_note
+            elif not expressible:
+                note = expr_note
+            elif veto:
+                note = veto
+            else:
+                primitive, sparse_xw, note = bind_sparse()
+                path = "sparse"
+                note = "; ".join(filter(None, (note, expr_note)))
+        note = "; ".join(filter(None, (note, operand_note)))
+        layers.append(LayerPlan(
+            index=i, op_kind=kind, d_in=d_in, d_out=d_out,
+            feature_path=path, primitive=primitive,
+            agg_primitive=agg_primitive, decision=decision,
+            sparse_xw=sparse_xw, note=note,
+            epilogue=(_epilogue_binding(config, is_last=(i == last),
+                                        sparse_path=(path == "sparse"))
+                      if epilogue else None),
+            attention=(_attention_binding(kind, config.gat_heads, d_out,
+                                          i == last, attention_fused,
+                                          operand or "bsr")
+                       if is_attention_arch(kind) else None),
+            layout=layout, operand=operand,
+        ))
+    return layers
+
+
 @span("layout")
 def _resolve_layout(
     graph: CSRGraph,
-    f_dim: int,
-    backend_name: str,
-    fused: bool,
     layout: "LayoutPlan | str | None",
-    br: Optional[int],
-    bc: Optional[int],
-    interpret: Optional[bool],
-    n_heads: int = 0,
-    attention: bool = False,
+    br: Optional[int] = None,
+    bc: Optional[int] = None,
+    *,
+    source: Optional[str] = None,
 ) -> LayoutPlan:
-    """Turn a ``layout=`` argument into a concrete ``LayoutPlan``.
+    """Turn a ``layout=`` argument into a concrete ``LayoutPlan``: the one
+    resolver of ``lower`` and ``lower_sampled``.
 
-    * ``None`` — the un-autotuned fallback: identity order, explicit
-      ``br``/``bc`` when given, adaptive ``bc`` otherwise (satellite fix:
-      small graphs stop lane-padding to 128).
-    * ``"auto"`` — the full layout stage: order selection + tile
-      autotuning with the disk cache (``core/layout.py:plan_layout``).
-    * ``"none" | "degree" | "rcm"`` — that order with the fallback tile
-      (or an explicit ``br``/``bc``; no measurement — deterministic, what
-      the parity tests pin).
+    * ``None`` / ``"none"`` — identity order.
+    * ``"auto"`` — ``_select_order``'s block-count rule: degree or RCM,
+      whichever packs fewer BSR blocks at the default tile, and only when
+      it cuts the count by at least 10%; otherwise the identity.
+    * ``"degree"`` / ``"rcm"`` — that order.
     * a ``LayoutPlan`` — passes through untouched.
 
-    Explicit ``br``/``bc`` combined with ``"auto"`` or a ``LayoutPlan``
-    is a conflict (the layout carries the tile) and raises rather than
-    silently discarding the caller's tile.
+    The tile is ``br``/``bc`` (``None``: 8 rows, adaptive ``bc``), with
+    the graph's block count taken at it. Explicit ``br``/``bc`` with a
+    ``LayoutPlan`` is a conflict (the plan carries the tile) and raises.
+    A pass whose operands are not cut from the full graph names itself in
+    ``source`` (``"sampled"``: bucketed per-batch blocks): its ``br``/``bc``
+    replace any plan's tile, and no block count is taken.
     """
-    if isinstance(layout, LayoutPlan) or layout == "auto":
+    if isinstance(layout, LayoutPlan):
+        if source is not None:
+            return dataclasses.replace(layout, br=br, bc=bc, n_blocks=0,
+                                       padding_waste=0.0, source=source)
         if br is not None or bc is not None:
             raise ValueError(
-                f"explicit br/bc conflict with layout={layout!r}: the "
-                f"layout carries the tile — pass one or the other")
-        if isinstance(layout, LayoutPlan):
-            return layout
-        return plan_layout(graph, f_dim, backend=backend_name, fused=fused,
-                           interpret=interpret, n_heads=n_heads,
-                           attention=attention)
-    if layout is None or layout == "none":
-        lp = default_layout(graph, br=br, bc=bc)
-        if br is not None or bc is not None:
+                "explicit br/bc conflict with a LayoutPlan: the layout "
+                "carries the tile — pass one or the other")
+        return layout
+    mode, g_r, perm, inv = _select_order(graph, layout or "none")
+    if source is not None:
+        lp = LayoutPlan(order="none", br=br, bc=bc, source=source)
+    else:
+        lp = default_layout(g_r, br=br, bc=bc)
+        if layout == "auto":
+            lp.source = "auto"
+        elif mode != "none":
+            lp.source = "requested"
+        elif br is not None or bc is not None:
             lp.source = "explicit"
-        return lp
-    mode, g_r, perm, inv = _select_order(graph, layout)  # validates mode
     if mode == "none":
-        return default_layout(graph, br=br, bc=bc)
-    lp = default_layout(g_r, br=br, bc=bc)
+        return lp
     return dataclasses.replace(lp, order=mode, perm=perm, inv_perm=inv,
-                               source="requested", reordered_graph=g_r)
+                               reordered_graph=g_r)
 
 
 @span("lower")
@@ -887,17 +899,17 @@ def lower(
     lower onto the fused flash-attention kernels on pallas/xla, over the
     operand format the fill rule picks (pallas: BSR or CSR row gather).
 
-    ``layout`` selects the layout-optimization stage (DESIGN.md §9):
-    ``"auto"`` reorders the graph (degree / RCM, whichever packs BSR blocks
-    densest) and autotunes the ``(br, bc, bf)`` tile with the disk-cached
-    microbenchmark; every sparse operand is then built once from the
-    reordered graph, and the plan carries ``perm``/``inv_perm`` so
+    ``layout`` selects the node order of the layout stage (DESIGN.md §9,
+    ``_resolve_layout``): ``None`` / ``"none"`` keep the identity,
+    ``"degree"`` / ``"rcm"`` reorder, and ``"auto"`` reorders only when
+    that cuts the BSR block count by at least 10% — the rule
+    ``lower_sampled`` applies. Every sparse operand is then built once from
+    the reordered graph, and the plan carries ``perm``/``inv_perm`` so
     ``GNNModel.apply`` permutes features in and un-permutes outputs —
-    results are bit-for-bit up to the permutation. Explicit ``br``/``bc``
-    keep their legacy meaning (``bc=None`` now defaults adaptively instead
-    of lane-padding small graphs to 128) but conflict with ``"auto"`` / a
-    ``LayoutPlan`` — the layout carries the tile, so that combination
-    raises instead of silently dropping the caller's tile.
+    results are bit-for-bit up to the permutation. ``br``/``bc`` set the
+    tile (``bc=None`` adapts to the graph instead of lane-padding small
+    graphs to 128); with a ``LayoutPlan``, which carries its own tile, they
+    raise instead of silently dropping the caller's tile.
     """
     backend = select_backend(engine)
     kind = config.kind
@@ -905,19 +917,12 @@ def lower(
 
     agg = effective_aggregation(config)
 
-    emit_fused_epi = (use_fused and fuse_epilogue
-                      and epilogue_fusable(config, agg))
+    emit_epilogue = (use_fused and fuse_epilogue
+                     and epilogue_fusable(config, agg))
     is_attn = is_attention_arch(kind)
     emit_attn = (use_fused and fuse_attention and is_attn
                  and backend.name in ("pallas", "xla"))
-    # the autotuner measures at the width the aggregation SpMM actually
-    # runs: every arch aggregates post-transform tensors of the hidden
-    # width (GCN A·(XW), SAGE A·(XWn), GIN-reassociated A·u)
-    agg_width = dims[1] if len(dims) > 1 else dims[0]
-    lp = _resolve_layout(graph, agg_width, backend.name, emit_fused_epi,
-                         layout, br, bc, interpret,
-                         n_heads=config.gat_heads if is_attn else 0,
-                         attention=emit_attn)
+    lp = _resolve_layout(graph, layout, br, bc)
     if lp.permutes:
         graph_exec = (lp.reordered_graph if lp.reordered_graph is not None
                       else permute_graph(graph, lp.inv_perm))
@@ -926,7 +931,6 @@ def lower(
     else:
         graph_exec = graph
         features_exec = None if features is None else np.asarray(features)
-    n_nodes = graph_exec.n_rows
 
     # the aggregation operand's format, by the fill the layout counted
     # (backends with one format ignore it); attention takes it as the SpMM
@@ -935,7 +939,7 @@ def lower(
            else "auto")
     graph_op = make_fused_aggregate(
         graph_exec, agg, br=lp.br, bc=lp.bc, interpret=interpret,
-        engine=backend, bf=lp.bf or None, build_attention=emit_attn, fmt=fmt)
+        engine=backend, build_attention=emit_attn, fmt=fmt)
     operand = getattr(graph_op.fwd_operand, "format", "")
     operand_note = f"{operand} operand" if operand else ""
     if operand and lp.n_blocks:
@@ -947,7 +951,6 @@ def lower(
     if lp.reordered_graph is not None:
         lp = dataclasses.replace(lp, reordered_graph=None)
 
-    emit_epilogue = emit_fused_epi
     attn_bound = emit_attn and graph_op.aggregate_attention is not None
     if is_attn:
         agg_primitive = (f"{backend.name}.spmm_attention" if attn_bound
@@ -962,11 +965,15 @@ def lower(
     else:
         agg_primitive = f"{backend.name}.spmm_transposed_vjp"
 
-    s_input = 0.0
-    if features is not None:
-        features = np.asarray(features)
+    def bind_sparse():
+        # operand of the (possibly reordered) feature matrix; bc adapts to
+        # the feature dim — X's columns are features, not graph nodes, so
+        # the adjacency tile does not apply
+        return (f"{backend.name}.feature_matmul_sparse",
+                backend.feature_matmul_sparse(
+                    features_exec, br=lp.br, bc=None, interpret=interpret),
+                "")
 
-    layers: list[LayerPlan] = []
     with span("decide"):
         formats = [getattr(op, "format", None)
                    for op in (graph_op.fwd_operand, graph_op.bwd_operand)]
@@ -975,75 +982,29 @@ def lower(
             if is_attn:  # attention layers on each format's fused kernels
                 count(f"attention_{name}", config.n_layers
                       if attn_bound and operand == name else 0)
-        for i in range(config.n_layers):
-            d_in, d_out = dims[i], dims[i + 1]
-            if i == 0:
-                if features is not None:
-                    decision = decide_execution_path(
-                        features, gamma=gamma, n_hidden=d_out)
-                    s_input = decision.sparsity
-                else:
-                    decision = decide_execution_path_from_stats(
-                        0.0, n_nodes, d_in, d_out, gamma=gamma)
-            else:
-                s_est = estimate_activation_sparsity(config.activation)
-                decision = decide_execution_path_from_stats(
-                    s_est, n_nodes, d_in, d_out, gamma=gamma)
-
-            sparse_xw = None
-            note = ""
-            if decision.mode == "sparse":
-                expressible, expr_note = _sparse_expressible(kind)
-                if i == 0 and features is not None and use_fused and expressible:
-                    # operand of the (possibly reordered) feature matrix; bc
-                    # adapts to the feature dim — X's columns are features, not
-                    # graph nodes, so the adjacency tile does not apply
-                    sparse_xw = backend.feature_matmul_sparse(
-                        features_exec, br=lp.br, bc=None, interpret=interpret)
-                    path = "sparse"
-                    primitive = f"{backend.name}.feature_matmul_sparse"
-                    note = expr_note
-                else:
-                    path = "dense"
-                    primitive = f"{backend.name}.feature_matmul_dense"
-                    if not use_fused:
-                        note = "sparse profitable but fusion disabled (use_fused=False)"
-                    elif i > 0:
-                        note = ("sparse profitable but activations are runtime "
-                                "values; no pre-built operand — dense fallback")
-                    elif features is None:
-                        note = "feature matrix unknown at lowering time"
-                    else:
-                        note = expr_note
-            else:
-                path = "dense"
-                primitive = f"{backend.name}.feature_matmul_dense"
-
-            epilogue = None
-            if emit_epilogue:
-                epilogue = _epilogue_binding(
-                    config, is_last=(i == config.n_layers - 1),
-                    sparse_path=sparse_xw is not None)
-            attention = None
-            if is_attn:
-                attention = _attention_binding(
-                    kind, config.gat_heads, d_out,
-                    i == config.n_layers - 1, attn_bound, operand or "bsr")
-
-            if operand_note:
-                note = f"{note}; {operand_note}" if note else operand_note
-            layers.append(LayerPlan(
-                index=i, op_kind=kind, d_in=d_in, d_out=d_out,
-                feature_path=path, primitive=primitive,
-                agg_primitive=agg_primitive, decision=decision,
-                sparse_xw=sparse_xw, note=note, epilogue=epilogue,
-                attention=attention, layout=lp, operand=operand,
-            ))
+        if features is not None:
+            decision0 = decide_execution_path(
+                features, gamma=gamma, n_hidden=dims[1])
+        else:
+            decision0 = decide_execution_path_from_stats(
+                0.0, graph_exec.n_rows, dims[0], dims[1], gamma=gamma)
+        layers = _plan_layers(
+            config, gamma=gamma, n_rows=graph_exec.n_rows,
+            decision0=decision0, sparse_input=use_fused,
+            off_note="sparse profitable but fusion disabled (use_fused=False)",
+            veto=("" if features is not None
+                  else "feature matrix unknown at lowering time"),
+            bind_sparse=bind_sparse,
+            dense_primitive=f"{backend.name}.feature_matmul_dense",
+            agg_primitive=agg_primitive, epilogue=emit_epilogue,
+            attention_fused=attn_bound, layout=lp, operand=operand,
+            operand_note=operand_note)
 
     plan = ModelPlan(
         layers=layers, backend=backend.name, gamma=gamma, arch=kind,
-        aggregation=agg, feature_sparsity=s_input, graph_op=graph_op,
-        layout=lp,
+        aggregation=agg,
+        feature_sparsity=decision0.sparsity if features is not None else 0.0,
+        graph_op=graph_op, layout=lp,
     )
     check_plan(plan, mode=validate, graph=graph_exec)
     return plan

@@ -444,13 +444,12 @@ def _ceil_to(x: int, m: int) -> int:
 
 
 def adaptive_bc(n_cols: int, max_bc: int = 128) -> int:
-    """Fallback block-column width for an un-autotuned ``csr_to_bsr``.
+    """Default block-column width of ``csr_to_bsr`` when none is given.
 
     Largest lane tile in {128, 64, 32, 16, 8} whose column padding wastes
     at most 1/8 of the padded width. Large graphs keep the full 128-lane
     tile; small graphs (nell's 263 nodes) stop shipping a mostly-zero
-    padded block-column through the DMA. The autotuner (core/layout.py)
-    overrides this with a measured choice when one is cached.
+    padded block-column through the DMA.
     """
     for bc in (128, 64, 32, 16, 8):
         if bc > max_bc:
@@ -520,9 +519,9 @@ def csr_to_bsr(csr: CSRGraph, br: int = 8, bc: Optional[int] = None) -> BSRMatri
 
 def bsr_block_count(csr: CSRGraph, br: int, bc: int) -> int:
     """Block count of ``csr_to_bsr(csr, br, bc)`` without materialising the
-    blocks — the autotuner's cost-model primitive (distinct
-    (block-row, block-col) pairs plus one explicit zero block per empty
-    block-row, exactly the conversion's output size)."""
+    blocks — what the layout stage's order rule and fill rule count
+    (distinct (block-row, block-col) pairs plus one explicit zero block per
+    empty block-row, exactly the conversion's output size)."""
     n_block_rows = _ceil_to(csr.n_rows, br) // br
     n_block_cols = max(_ceil_to(csr.n_cols, bc) // bc, 1)
     rows = np.repeat(np.arange(csr.n_rows, dtype=np.int64),

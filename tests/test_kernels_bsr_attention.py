@@ -4,7 +4,7 @@ Four layers of coverage:
 
 * kernel vs edge-list oracle — forward + grads at 1e-4 across square /
   bipartite geometries, both inners (Pallas-interpret and XLA reference),
-  single- and multi-head, with and without a cached ``bf`` lane tile;
+  single- and multi-head, with and without a ``bf`` lane tile;
 * online-softmax recurrence goldens — a hand-built two-block row whose
   second block raises the running max, pinning the rescale path and the
   saved (m, l) statistics against closed-form values;
@@ -28,7 +28,6 @@ import pytest
 
 from repro.backends import get_backend
 from repro.backends.registry import edge_softmax_aggregate
-from repro.core.layout import graph_fingerprint
 from repro.core.lowering import lower, lower_sampled
 from repro.graph.csr import csr_from_edges
 from repro.kernels import ops as kops
@@ -216,18 +215,6 @@ def test_plan_binds_fused_attention_by_default(rng, kind, engine):
     gather = lower(cfg, g, x, engine="gather")
     assert gather.layers[0].agg_primitive == \
         "gather.segment_softmax_aggregate"
-
-
-def test_layout_fingerprint_keys_attention_separately(rng):
-    """Satellite: attention plans must not shadow SpMM plans in the
-    autotuner cache — the flag and the head count are part of the key."""
-    g = _graph(rng)
-    base = graph_fingerprint(g, 16, "pallas", True)
-    attn4 = graph_fingerprint(g, 16, "pallas", True, n_heads=4,
-                              attention=True)
-    attn8 = graph_fingerprint(g, 16, "pallas", True, n_heads=8,
-                              attention=True)
-    assert len({base, attn4, attn8}) == 3
 
 
 # ---------------------------------------------------------------------------

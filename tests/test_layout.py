@@ -1,6 +1,6 @@
-"""Layout-optimization stage (DESIGN.md §9): reorder correctness, RCM
-bandwidth reduction, adaptive-bc fallback, autotuner cache determinism, and
-the permutation round-trip contract — reordered plans must match the
+"""Layout stage (DESIGN.md §9): reorder correctness, RCM bandwidth
+reduction, adaptive-bc fallback, the ``"auto"`` order rule, and the
+permutation round-trip contract — reordered plans must match the
 unreordered baseline (fwd + grads, 1e-4) across single-device, distributed
 and mini-batch trainers, in the caller's node order."""
 import json
@@ -14,14 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import layout as layout_mod
-from repro.core.layout import (
-    LayoutPlan,
-    cached_layout,
-    choose_order,
-    graph_fingerprint,
-    plan_layout,
-)
+from repro.core.layout import _select_order, choose_order
 from repro.core.lowering import lower, lower_sampled
 from repro.graph.csr import (
     adaptive_bc,
@@ -145,52 +138,8 @@ def test_bsr_stats_and_block_count(rng):
 
 
 # ---------------------------------------------------------------------------
-# Autotuner: cache determinism, cost model, fingerprints
+# Order selection
 # ---------------------------------------------------------------------------
-
-def test_autotuner_cache_hit_never_remeasures(rng, tmp_path):
-    g = _graph(rng)
-    cache = str(tmp_path / "layouts.json")
-    first = plan_layout(g, 16, backend="xla", fused=True, cache_path=cache,
-                        measure=True)
-    measured = layout_mod.measure_calls()
-    assert first.source == "measured"
-    second = plan_layout(g, 16, backend="xla", fused=True, cache_path=cache)
-    assert layout_mod.measure_calls() == measured  # no re-measure
-    assert second.source == "cache"
-    assert (second.order, second.br, second.bc, second.bf) == \
-        (first.order, first.br, first.bc, first.bf)
-    if first.perm is not None:
-        np.testing.assert_array_equal(first.perm, second.perm)
-    # a different feature dim is a different fingerprint -> fresh measure
-    assert graph_fingerprint(g, 16, "xla", True) != \
-        graph_fingerprint(g, 32, "xla", True)
-    third = plan_layout(g, 32, backend="xla", fused=True, cache_path=cache,
-                        measure=True)
-    assert third.source == "measured"
-    assert layout_mod.measure_calls() > measured
-
-
-def test_cost_model_fallback_is_deterministic(rng, tmp_path):
-    """Interpret-mode path: no timing, same graph -> same layout, twice."""
-    g = _graph(rng)
-    a = plan_layout(g, 16, backend="pallas", fused=True, measure=False,
-                    cache_path=str(tmp_path / "a.json"))
-    b = plan_layout(g, 16, backend="pallas", fused=True, measure=False,
-                    cache_path=str(tmp_path / "b.json"))
-    assert a.source == b.source == "cost-model"
-    assert (a.order, a.br, a.bc, a.bf) == (b.order, b.br, b.bc, b.bf)
-
-
-def test_cached_layout_is_lookup_only(rng, tmp_path):
-    g = _graph(rng)
-    cache = str(tmp_path / "layouts.json")
-    assert cached_layout(g, 16, cache_path=cache) is None  # miss: no tuning
-    plan_layout(g, 16, backend="xla", fused=True, cache_path=cache,
-                measure=False)
-    hit = cached_layout(g, 16, cache_path=cache)
-    assert hit is not None and hit.source == "cache"
-
 
 def test_choose_order_needs_meaningful_gain(rng):
     """A near-diagonal graph reordering cannot improve must stay 'none' —
@@ -387,22 +336,38 @@ def test_distributed_within_rank_reorder_parity():
 # Lowering integration
 # ---------------------------------------------------------------------------
 
-def test_lower_auto_uses_cost_model_in_interpret_mode(rng, monkeypatch,
-                                                      tmp_path):
-    """layout="auto" through lower() on the Pallas (interpret) backend
-    lands on the cost model, not a Python-interpreter wall-time."""
-    monkeypatch.setenv("MORPHLING_LAYOUT_CACHE",
-                       str(tmp_path / "layouts.json"))
+def test_lower_auto_uses_cost_model_in_interpret_mode(rng, tmp_path,
+                                                      monkeypatch):
+    """layout="auto" means one thing in both passes: ``_select_order``'s
+    block-count rule at the default tile, with no timing and no file
+    written. ``lower`` and ``lower_sampled`` pick the same order, on a
+    graph where a reorder pays and on one where it does not."""
+    monkeypatch.setenv("HOME", str(tmp_path))
     n, f = 48, 32
-    g = _graph(rng)
-    x = _features(rng, n, f, 0.95)
     cfg = GNNConfig(kind="GCN", layer_dims=[f, 12, 4])
-    if jax.default_backend() == "tpu":
-        pytest.skip("interpret-mode path is the off-TPU case")
-    plan = lower(cfg, g, x, engine="pallas", interpret=True, layout="auto")
-    assert plan.layout.source in ("cost-model", "cache")
-    plan2 = lower(cfg, g, x, engine="pallas", interpret=True, layout="auto")
-    assert plan2.layout.source == "cache"  # second lowering hits the cache
+    idx = np.arange(n)
+    shuffled = rng.permutation(n)
+    ring = csr_from_edges(np.concatenate([idx, shuffled]),
+                          np.concatenate([idx, np.roll(shuffled, 1)]), n)
+    path = csr_from_edges(np.concatenate([idx, idx[:-1]]),
+                          np.concatenate([idx, idx[1:]]), n)
+    orders = set()
+    for g in (ring, path):
+        x = _features(rng, n, f, 0.95)
+        want = _select_order(g, "auto")[0]
+        full = lower(cfg, g, x, engine="pallas", interpret=True,
+                     layout="auto")
+        sampled = lower_sampled(cfg, g, x, fanouts=(4, 4), batch_size=16,
+                                engine="xla", seed=0, layout="auto")
+        assert full.layout.order == sampled.layout.order == want
+        assert (full.layout.source, sampled.layout.source) == \
+            ("auto", "sampled")
+        if full.layout.permutes:
+            np.testing.assert_array_equal(full.layout.perm,
+                                          sampled.layout.perm)
+        orders.add(want)
+    assert "none" in orders and len(orders) == 2  # one reorder, one not
+    assert list(tmp_path.iterdir()) == []  # no cache file anywhere
 
 
 def test_default_lowering_keeps_identity_order(rng):

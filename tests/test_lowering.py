@@ -12,7 +12,14 @@ from repro.backends import (
     select_backend,
 )
 from repro.core.dsl import GNNProgram
-from repro.core.lowering import lower
+from repro.core.halo import build_distributed_graph
+from repro.core.lowering import (
+    effective_aggregation,
+    lower,
+    lower_distributed,
+    lower_sampled,
+)
+from repro.core.partitioner import hierarchical_partition
 from repro.core.sparsity import PAPER_GAMMA_DEFAULT, decide_execution_path
 from repro.graph.csr import csr_from_edges
 from repro.graph.datasets import DATASET_SPECS
@@ -110,6 +117,48 @@ def test_gamma_threshold_moves_decisions(rng):
     assert hidden.decision.mode == "sparse"
     assert hidden.feature_path == "dense"
     assert "fallback" in hidden.note
+
+
+# ---------------------------------------------------------------------------
+# One planner behind the three lowering passes
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("arch,agg", [
+    ("GCN", "gcn"), ("SAGE", "mean"), ("GIN", "sum"), ("GAT", "sum"),
+])
+@pytest.mark.parametrize("sparsity", [0.95, 0.0], ids=["sparse", "dense"])
+def test_lowering_passes_share_layer_records(rng, arch, agg, sparsity):
+    """``lower``, full-fanout ``lower_sampled`` and two-rank
+    ``lower_distributed`` see the same rows and features, so their layer
+    records agree: every layer's decision and feature path, its epilogue
+    and its attention record. Primitive names and notes name each pass's
+    own operands, and are not compared."""
+    n, f, c = 48, 32, 4
+    g = _graph(rng, n)
+    x = _features(rng, n, f, sparsity)
+    cfg = GNNConfig(kind=arch, layer_dims=[f, 8, 8, c], aggregation=agg,
+                    gat_heads=2 if arch == "GAT" else 1)
+    dist = build_distributed_graph(
+        g, x, rng.integers(0, c, n).astype(np.int32), np.ones(n, bool),
+        hierarchical_partition(g, 2), aggregation=effective_aggregation(cfg))
+    plans = [
+        lower(cfg, g, x, engine="xla"),
+        lower_sampled(cfg, g, x, fanouts=n, batch_size=n, n_buckets=1,
+                      engine="xla", seed=0),
+        lower_distributed(cfg, dist),
+    ]
+    want = "sparse" if sparsity else "dense"
+    assert [p.layers[0].feature_path for p in plans] == [want] * 3
+    for first, *rest in zip(*(p.layers for p in plans)):
+        assert (first.epilogue is None) == (arch == "GAT")
+        assert (first.attention is None) == (arch != "GAT")
+        for other in rest:
+            assert other.decision == first.decision, first.index
+            assert other.feature_path == first.feature_path, first.index
+            assert other.epilogue == first.epilogue, first.index
+            assert other.attention == first.attention, first.index
+    hidden = [l for p in plans for l in p.layers[1:]]
+    assert all(l.feature_path == "dense" for l in hidden)
 
 
 # ---------------------------------------------------------------------------
